@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.kernels_fn import gaussian
 from repro.kernels.kde_sampler.sharded import ShardedBlocks
 from repro.obs.export import telemetry_block
@@ -52,8 +52,8 @@ def _frozen_block_sums(mesh, kernel, num_blocks_per_shard, data_axes=("data",)):
         kv = kernel.pairwise(y, x_shard)
         return kv.reshape(y.shape[0], num_blocks_per_shard, bs).sum(-1)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
-                             out_specs=P(None, axes)))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
+                                 out_specs=P(None, axes)))
 
 
 def _host_orchestrated_walk(mesh, x, xs, kernel, starts, length, bs, rng):
@@ -100,14 +100,15 @@ def _scaling(quick: bool, mesh, devices: int) -> dict:
     per shard and the sweep reaches 10^6 points in quick mode.  Each entry
     carries a measured-roofline fraction: per-device operand bytes (local
     level-1 subsample read + owner-shard level-2 slab) and the one-psum
-    collective payload against ``chip_spec_for_backend()``.
+    collective payload against the chip's published peaks ("not
+    measured" on the CPU backend).
     """
-    from repro.roofline.analysis import (chip_spec_for_backend,
-                                         measured_roofline)
+    from repro.roofline.analysis import (NOT_MEASURED, device_chip_spec,
+                                         roofline_summary)
     sizes = [4096, 65536, 1048576] if quick else [
         4096, 65536, 262144, 1048576]
     w, length, d, s = 256, 4, 8, 16
-    spec = chip_spec_for_backend()
+    spec = device_chip_spec()
     rng = np.random.default_rng(0)
     entries = []
     for n in sizes:
@@ -133,24 +134,23 @@ def _scaling(quick: bool, mesh, devices: int) -> dict:
                      + w * bs * d * 4 // devices)
         coll_dev = 3 * w * devices * 4
         flops_dev = 2.0 * w * (num_blocks * s // devices + bs // devices) * d
-        mr = measured_roofline(t / length, flops_dev, bytes_dev, spec=spec,
-                               chips=devices,
-                               coll_bytes_per_device=coll_dev)
+        rl = roofline_summary(spec, t / length, flops_dev, bytes_dev,
+                              chips=devices, coll_bytes_per_device=coll_dev)
         emit(f"distributed_walk_scaling/n={n}_p{devices}",
              t * 1e6 / (w * length),
              f"steps_per_sec={sps:.0f};"
-             f"roofline_frac={mr.achieved_fraction:.3f};"
-             f"dominant={mr.dominant}")
+             f"roofline_frac={rl['fraction']};"
+             f"dominant={rl.get('dominant', 'not measured')}")
         entries.append(dict(
             n=n, block_size=bs, walkers=w, length=length, d=d,
             samples_per_block=s, steps_per_sec=sps,
             us_per_step=t / length * 1e6,
             modeled_bytes_per_device_step=bytes_dev,
             psum_bytes_per_device_step=coll_dev,
-            roofline=dict(fraction=mr.achieved_fraction,
-                          dominant=mr.dominant,
-                          achieved_bw=mr.achieved_bw)))
-    return dict(devices=devices, spec=spec.as_dict(), entries=entries)
+            roofline=rl))
+    return dict(devices=devices,
+                spec=spec.as_dict() if spec else NOT_MEASURED,
+                entries=entries)
 
 
 def run(quick: bool = False) -> None:
@@ -162,7 +162,7 @@ def run(quick: bool = False) -> None:
     x = rng.normal(0, 0.5, (n, d)).astype(np.float32)
     ker = gaussian(2.0)
     devices = len(jax.devices())
-    mesh = jax.make_mesh((devices,), ("data",))
+    mesh = make_mesh((devices,), ("data",))
     bs = max(int(np.sqrt(n)), 16)
 
     eng = ShardedBlocks(mesh, x, ker, block_size=bs, exact=True)

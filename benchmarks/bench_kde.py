@@ -32,6 +32,7 @@ from benchmarks.common import emit, timeit
 from repro.core.kde.base import ExactKDE, make_estimator
 from repro.core.kernels_fn import (exponential, gaussian, laplacian,
                                    rational_quadratic)
+from repro.launch.mesh import make_mesh
 from repro.obs.export import telemetry_block
 
 _JSON_PATH = Path(__file__).resolve().parent.parent / "BENCH_kde.json"
@@ -87,7 +88,7 @@ def _mesh(quick: bool, rows, results):
     q = rng.normal(0, 0.4, (m, d)).astype(np.float32)
     ker = gaussian(2.0)
     truth = np.asarray(ExactKDE(x, ker).query(q))
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     out = []
     for name, est in (("sharded_exact", ShardedKDE(mesh, x, ker,
                                                    exact=True)),
@@ -183,7 +184,7 @@ def _precision_scaling(quick: bool, rows, results):
     sizes = [65536, 262144, 1048576] if quick else [
         65536, 262144, 524288, 1048576]
     d, m = 16, 64
-    spec = _roofline.chip_spec_for_backend()
+    spec = _roofline.device_chip_spec()
     entries = []
     for n in sizes:
         rng = np.random.default_rng(0)
@@ -202,14 +203,11 @@ def _precision_scaling(quick: bool, rows, results):
             # the f32 accumulator are tile-resident).
             bytes_moved = float(n) * d * in_bytes + m * d * 4 + m * 4
             flops = 2.0 * n * m * d
-            mr = _roofline.measured_roofline(t, flops, bytes_moved,
-                                             spec=spec)
+            rl = _roofline.roofline_summary(spec, t, flops, bytes_moved)
             per[prec] = dict(us_per_batch=us,
                              evals_per_sec=n * m / t,
                              vals=np.asarray(est.query(q), np.float64),
-                             roofline=dict(fraction=mr.achieved_fraction,
-                                           dominant=mr.dominant,
-                                           achieved_bw=mr.achieved_bw))
+                             roofline=rl)
         rel = float(np.max(np.abs(per["bf16"]["vals"] / per["f32"]["vals"]
                                   - 1.0)))
         speedup = per["f32"]["us_per_batch"] / per["bf16"]["us_per_batch"]
@@ -217,14 +215,16 @@ def _precision_scaling(quick: bool, rows, results):
             f"kde_precision/n={n}", per["bf16"]["us_per_batch"] / m,
             f"bf16_speedup={speedup:.2f}x;rel_err={rel:.2e};"
             f"bound={2 * BF16_REL_ERR:.2e};"
-            f"roofline_frac={per['bf16']['roofline']['fraction']:.3f}"))
+            f"roofline_frac={per['bf16']['roofline']['fraction']}"))
         entries.append(dict(
             n=n, d=d, m=m, bf16_speedup=speedup, bf16_rel_err=rel,
             rel_err_bound=2 * BF16_REL_ERR,
             f32={k: v for k, v in per["f32"].items() if k != "vals"},
             bf16={k: v for k, v in per["bf16"].items() if k != "vals"}))
-    results["precision"] = dict(kernel="gaussian", spec=spec.as_dict(),
-                                entries=entries)
+    results["precision"] = dict(
+        kernel="gaussian",
+        spec=spec.as_dict() if spec else _roofline.NOT_MEASURED,
+        entries=entries)
 
 
 def run(quick: bool = False):

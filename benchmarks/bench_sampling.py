@@ -142,13 +142,14 @@ def _walk_scaling(quick: bool, rows: list):
     Each entry also carries a measured-roofline fraction: modeled per-step
     operand bytes (cached level-1 read + level-2 stratum slab + CDF lanes)
     and kernel-eval flops against the backend's
-    ``roofline.analysis.chip_spec_for_backend()`` peaks.
+    chip's published peaks (``roofline.analysis.CHIP_PEAKS``; "not
+    measured" on the CPU backend).
     """
     sizes = [4096, 65536, 1048576] if quick else [
         4096, 16384, 65536, 262144, 1048576]
     walkers, steps, d = 256, 4, 16
     fb = _roofline.dtype_bytes("float32")
-    spec = _roofline.chip_spec_for_backend()
+    spec = _roofline.device_chip_spec()
     entries = []
     base_sps = None
     for n in sizes:
@@ -172,13 +173,13 @@ def _walk_scaling(quick: bool, rows: list):
         bytes_per_step = walkers * (cols * d + wbs * d
                                     + 4 * (w_blocks + wbs)) * fb
         flops_per_step = 2.0 * walkers * (cols + wbs) * d
-        mr = _roofline.measured_roofline(t / steps, flops_per_step,
-                                         bytes_per_step, spec=spec)
+        rl = _roofline.roofline_summary(spec, t / steps, flops_per_step,
+                                        bytes_per_step)
         rows.append(emit(
             f"sampling/walk_scaling/n={n}", t / steps * 1e6,
             f"steps_per_sec={sps:.0f};cliff_ratio={cliff:.2f};"
             f"evals_per_step={evals_per_step};"
-            f"roofline_frac={mr.achieved_fraction:.3f}"))
+            f"roofline_frac={rl['fraction']}"))
         entries.append(dict(
             n=n, walkers=walkers, steps=steps, d=d,
             steps_per_sec=sps, us_per_step=t / steps * 1e6,
@@ -188,10 +189,9 @@ def _walk_scaling(quick: bool, rows: list):
             kernel_evals_per_step=evals_per_step,
             modeled_bytes_per_step=bytes_per_step,
             modeled_flops_per_step=flops_per_step,
-            roofline=dict(fraction=mr.achieved_fraction,
-                          dominant=mr.dominant,
-                          achieved_bw=mr.achieved_bw)))
-    return dict(walkers=walkers, steps=steps, d=d, spec=spec.as_dict(),
+            roofline=rl))
+    return dict(walkers=walkers, steps=steps, d=d,
+                spec=spec.as_dict() if spec else _roofline.NOT_MEASURED,
                 entries=entries,
                 cliff_ratio_65536=next(
                     (e["cliff_ratio_vs_4096"] for e in entries

@@ -36,6 +36,8 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else set(BENCHES)
     only -= set(args.skip.split(",")) if args.skip else set()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -8,7 +8,7 @@ boxes; everything in ``repro.core`` is written against this interface.
 Backends
 --------
 * ``ExactKDE``      -- brute force oracle (the Pallas ``kde_rowsum`` kernel on
-                       TPU; a blocked jnp sweep on CPU).
+                       TPU; a blocked jnp sweep elsewhere).
 * ``RSKDE``         -- uniform random sampling, the ``p = 1`` estimator the
                        paper describes in Section 3.1.
 * ``StratifiedKDE`` -- beyond-paper variance reduction: the dataset is split
@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kernels_fn import Kernel
+from repro.kernels import platform as _platform
 from repro.obs import counters as _c
 
 
@@ -92,13 +93,14 @@ class KDEBase:
 
 
 class ExactKDE(KDEBase):
-    """Brute-force oracle; the Pallas kernel computes this on TPU."""
+    """Brute-force oracle; the Pallas kernel computes this on TPU
+    (``use_pallas=None`` lets ``kernels.platform`` decide)."""
 
     def __init__(self, x, kernel: Kernel, chunk: int = 8192,
-                 use_pallas: bool = False, precision: str = "f32"):
+                 use_pallas: Optional[bool] = None, precision: str = "f32"):
         super().__init__(x, kernel, precision=precision)
         self.chunk = chunk
-        self.use_pallas = use_pallas
+        self.use_pallas = _platform.resolve(use_pallas, None, kernel.name)[0]
 
     def query(self, y: jnp.ndarray) -> jnp.ndarray:
         """Exact row sums; m*n kernel evals per call."""
@@ -209,16 +211,16 @@ class ExactBlockKDE(StratifiedKDE):
     (Algorithm 5.1 computes the probability q_uv with which the sampler picks
     an edge; a deterministic level-1 read makes q exactly recomputable).
 
-    With ``use_pallas=True`` the sweep dispatches to the ``blocksum_pallas``
-    TPU kernel; otherwise it is one jitted jnp program reusing the
-    precomputed ``x_sq`` norms.
+    On the Pallas path (the default on TPU, ``kernels.platform``) the
+    sweep dispatches to the ``blocksum_pallas`` kernel; otherwise it is one
+    jitted jnp program reusing the precomputed ``x_sq`` norms.
     """
 
     def __init__(self, x, kernel: Kernel, block_size: int = 256,
-                 use_pallas: bool = False, precision: str = "f32"):
+                 use_pallas: Optional[bool] = None, precision: str = "f32"):
         super().__init__(x, kernel, block_size=block_size,
                          samples_per_block=block_size, precision=precision)
-        self.use_pallas = use_pallas
+        self.use_pallas = _platform.resolve(use_pallas, None, kernel.name)[0]
 
     def block_sums(self, y: jnp.ndarray) -> jnp.ndarray:
         """Exact (m, B) per-block sums; m*n evals per call."""
@@ -226,9 +228,14 @@ class ExactBlockKDE(StratifiedKDE):
         self.evals += y.shape[0] * self.n
         if self.use_pallas:
             from repro.kernels.kde_rowsum import ops as rs_ops
-            return rs_ops.kde_blocksum(y, self.x, self.kernel,
-                                       bn=self.block_size,
-                                       precision=self.precision)
+            bs = rs_ops.kde_blocksum(y, self.x, self.kernel,
+                                     bn=self.block_size,
+                                     precision=self.precision)
+            # the word the jnp program would return: its counters are the
+            # same static shape products
+            self.device_counters.note(_c.word(evals=y.shape[0] * self.n,
+                                              l1_reads=y.shape[0]))
+            return bs
         from repro.kernels.kde_sampler import ops as sampler_ops
         bs, cw = sampler_ops.exact_block_sums(y, self.x, self.x_sq,
                                               **self._static_cfg())

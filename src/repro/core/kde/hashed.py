@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.kde.base import KDEBase
 from repro.core.kernels_fn import Kernel
 from repro.ft import guards as _g
+from repro.kernels import platform as _platform
 
 
 class HashedKDE(KDEBase):
@@ -109,12 +110,8 @@ class HashedKDE(KDEBase):
             overflow_cap=self._build_kw["overflow_cap"])
         self._patcher = (_ops.HashPatcher(self.state, self.cell_width)
                          if self._dataset is not None else None)
-        use_pallas = self._use_pallas
-        interpret = self._interpret
-        if use_pallas is None:
-            use_pallas = _ops._sops.default_use_pallas()
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        use_pallas, interpret = _platform.resolve(
+            self._use_pallas, self._interpret, kernel.name)
         self._cfg = dict(kind=kernel.name, inv_bw=1.0 / kernel.bandwidth,
                          beta=getattr(kernel, "beta", 1.0),
                          pairwise=static_pairwise(kernel),

@@ -55,9 +55,12 @@ class Kernel:
 
 def _sq_dists(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     # ||x - y||^2 = ||x||^2 + ||y||^2 - 2 x.y ; clamp for numerical safety.
+    # Full f32 contract precision: a TPU's default f32 dot is one bf16
+    # pass, whose cancellation error swamps d2 for uncentered data.
     xx = jnp.sum(x * x, axis=-1)[:, None]
     yy = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xx + yy - 2.0 * (x @ y.T)
+    d2 = xx + yy - 2.0 * jnp.matmul(x, y.T,
+                                    precision=jax.lax.Precision.HIGHEST)
     return jnp.maximum(d2, 0.0)
 
 

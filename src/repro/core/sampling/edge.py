@@ -51,6 +51,7 @@ from repro.core.kde.base import ExactBlockKDE, StratifiedKDE
 from repro.core.kde.multilevel import MultiLevelKDE
 from repro.core.kernels_fn import Kernel
 from repro.ft import guards as _g
+from repro.kernels import platform as _platform
 from repro.obs import counters as _c
 
 # Flags a healthy pipeline may legitimately raise: truncated buckets and
@@ -184,12 +185,12 @@ class NeighborSampler:
             self.block_size = self._blocks.block_size
             self.num_blocks = self._blocks.num_blocks
             self.exact_blocks = exact_blocks
-            if use_pallas is None:
-                use_pallas = (_ops.default_use_pallas()
-                              if self._engine is None else False)
-            if interpret is None:
-                interpret = (jax.default_backend() != "tpu"
-                             and self._engine is None)
+            if self._engine is not None:
+                # the mesh engine's shard-local reads are jnp programs
+                use_pallas, interpret = False, False
+            else:
+                use_pallas, interpret = _platform.resolve(
+                    use_pallas, interpret, kernel.name)
             self._far_per_block = 1
             if level1 == "hash":
                 # Hashed level-1 (DESIGN.md §10): block masses estimated

@@ -33,6 +33,7 @@ class SparseGraph:
     weight: np.ndarray    # (m,) float64
     kde_queries: int = 0
     kernel_evals: int = 0
+    device_evals: int = 0     # the same count, from the counter words
 
     @property
     def num_edges(self) -> int:
@@ -109,6 +110,9 @@ def spectral_sparsify(x, kernel: Kernel, num_edges: int,
     g = SparseGraph(n, np.asarray(u, np.int64), np.asarray(v, np.int64),
                     np.asarray(w, np.float64))
     g.kernel_evals = nbr.evals + (0 if est is nbr.blocks else est.evals)
+    est_words = getattr(est, "device_counters", None)
+    g.device_evals = nbr.device_counters["evals"] + (
+        est_words["evals"] if est_words is not None else 0)
     # degree preprocessing + one forward level-1 read per drawn edge (the
     # reverse probability collapses onto the preprocessed degrees)
     drawn = ((t + batch - 1) // batch) * batch

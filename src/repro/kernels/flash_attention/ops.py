@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import platform as _platform
 from repro.kernels.flash_attention import kernel as _k
 from repro.kernels.flash_attention import ref as _ref
 
@@ -34,8 +35,7 @@ def flash_attention(q, k, v, causal=True, bq=128, bk=128, interpret=None,
 
 
 def _fwd_impl(q, k, v, causal, bq, bk, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _platform.interpret_mode(interpret)
     b, hq, sq, dh = q.shape
     skv = k.shape[2]
     scale = 1.0 / (dh ** 0.5)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels import platform as _platform
 from repro.kernels.kde_attention import kernel as _k
 from repro.kernels.kde_attention import ref as _ref
 
@@ -35,8 +36,7 @@ def kde_attention(q, k, v, *, top_p: int, bk: int = 256, stride: int = 8,
                   kv_valid: int | None = None,
                   interpret: bool | None = None) -> jnp.ndarray:
     """q (b, hq, dh); k, v (b, hkv, S, dh) -> (b, hq, dh).  S % bk == 0."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _platform.interpret_mode(interpret)
     b, hq, dh = q.shape
     hkv, s = k.shape[1], k.shape[2]
     group = hq // hkv

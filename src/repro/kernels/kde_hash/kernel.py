@@ -1,22 +1,19 @@
 """Pallas TPU kernel: weighted bucket-gather kernel evaluation.
 
 The hashed estimator's hot loop is "evaluate k(q_i, x_j) over each query's
-gathered (bucket member + FAR sample) rows and reduce with per-slot HT
+gathered (bucket member + FAR sample) rows and weight them by per-slot HT
 weights".  The gather itself is an XLA gather (dense (w, t, d) member
-coordinates); this kernel fuses the kernel-value math and the weighted
-reduction over one query tile, keeping the (bm, t, d) gathered rows in
-VMEM for a single pass.
+coordinates); this kernel fuses the kernel-value math and the weighting
+over one (query tile, slot tile), keeping the (bm, tt, d) gathered rows in
+VMEM for a single pass.  ``weighted_kv_pallas`` returns the (m, t)
+weighted values: the hashed query sums them (and screens them for a
+dominating HT sample), the hashed level-1 read scatters them into blocks
+(DESIGN.md §10).
 
-Two entry points over the same body:
-
-* ``weighted_kv_sum_pallas`` -- (m,) weighted row sums: the Definition 1.1
-  query estimate (NEAR + HT-FAR in one reduction).
-* ``weighted_kv_pallas``     -- (m, t) weighted kernel values: consumed by
-  the hashed level-1 block-sum scatter (DESIGN.md §10).
-
-The kernel-value math is ``ref.rowwise_kv`` itself (a static d-loop on the
-VPU -- per-query-row buckets have no matmul form), so interpret-mode runs
-reproduce the jnp oracle bitwise.
+The kernel-value math is ``ref.rowwise_kv`` itself, so interpret-mode runs
+reproduce the jnp oracle bitwise.  The slot axis is tiled only when a
+whole (bm, t, d) row tile would not fit ``XR_TILE_BYTES`` of VMEM; slot
+tiles are lane-dense (multiples of 128, padded slots carry weight 0).
 """
 from __future__ import annotations
 
@@ -28,70 +25,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.kde_hash import ref as _ref
-from repro.kernels.kde_rowsum.kernel import (exp_table_operand,
-                                             exp_table_spec, needs_exp_table)
+
+#: VMEM budget of one staged (bm, tt, d) gathered-row tile (double-buffered
+#: by the pipeliner, so twice this is resident)
+XR_TILE_BYTES = 2 * 1024 * 1024
 
 
-def _weighted_kv_kernel(q_ref, w_ref, xr_ref, *rest, kind, inv_bw, beta,
-                        reduce_sum, precision, has_table):
-    if has_table:
-        t_ref, o_ref = rest
-        table = t_ref[...]
-    else:
-        (o_ref,) = rest
-        table = None
+def slot_tile(bm: int, t: int, d: int) -> int:
+    """Slot-axis tile width: all ``t`` slots when the row tile fits the
+    budget, else the widest multiple of 128 that does.  The feature axis
+    is the tile's lane axis, so VMEM holds it padded to 128 lanes."""
+    row_bytes = bm * (-(-d // 128) * 128) * 4
+    if t * row_bytes <= XR_TILE_BYTES:
+        return t
+    return max(128, XR_TILE_BYTES // row_bytes // 128 * 128)
+
+
+def _weighted_kv_kernel(q_ref, w_ref, xr_ref, o_ref, *, kind, inv_bw, beta,
+                        precision):
     kv = _ref.rowwise_kv(q_ref[...], xr_ref[...], kind, inv_bw, beta,
-                         precision=precision, table=table)
-    kv = kv * w_ref[...]
-    if reduce_sum:
-        o_ref[...] = jnp.sum(kv, axis=1)
-    else:
-        o_ref[...] = kv
-
-
-def _call(q, wgt, xr, kind, inv_bw, beta, bm, interpret, reduce_sum,
-          precision="f32"):
-    m, d = q.shape
-    t = xr.shape[1]
-    has_table = needs_exp_table(kind, precision)
-    body = functools.partial(_weighted_kv_kernel, kind=kind, inv_bw=inv_bw,
-                             beta=beta, reduce_sum=reduce_sum,
-                             precision=precision, has_table=has_table)
-    if reduce_sum:
-        out_specs = pl.BlockSpec((bm,), lambda i: (i,))
-        out_shape = jax.ShapeDtypeStruct((m,), jnp.float32)
-    else:
-        out_specs = pl.BlockSpec((bm, t), lambda i: (i, 0))
-        out_shape = jax.ShapeDtypeStruct((m, t), jnp.float32)
-    in_specs = [pl.BlockSpec((bm, d), lambda i: (i, 0)),
-                pl.BlockSpec((bm, t), lambda i: (i, 0)),
-                pl.BlockSpec((bm, t, d), lambda i: (i, 0, 0))]
-    operands = [q, wgt, xr]
-    if has_table:
-        in_specs.append(exp_table_spec(lambda i: (0,)))
-        operands.append(exp_table_operand())
-    return pl.pallas_call(
-        body,
-        grid=(m // bm,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        # one output tile per query tile, no cross-step state: the single
-        # grid axis pipelines with double-buffered gather-row copies
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(*operands)
-
-
-def weighted_kv_sum_pallas(q: jnp.ndarray, wgt: jnp.ndarray, xr: jnp.ndarray,
-                           kind: str, inv_bw: float, beta: float = 1.0,
-                           bm: int = 32, interpret: bool = False,
-                           precision: str = "f32"):
-    """q (m, d), wgt (m, t), xr (m, t, d) -> (m,) weighted kernel-value
-    sums ``sum_j wgt_ij k(q_i, xr_ij)``; m must be a multiple of bm."""
-    return _call(q, wgt, xr, kind, inv_bw, beta, bm, interpret,
-                 reduce_sum=True, precision=precision)
+                         precision=precision)
+    o_ref[...] = kv * w_ref[...]
 
 
 def weighted_kv_pallas(q: jnp.ndarray, wgt: jnp.ndarray, xr: jnp.ndarray,
@@ -99,6 +53,28 @@ def weighted_kv_pallas(q: jnp.ndarray, wgt: jnp.ndarray, xr: jnp.ndarray,
                        bm: int = 32, interpret: bool = False,
                        precision: str = "f32"):
     """q (m, d), wgt (m, t), xr (m, t, d) -> (m, t) weighted kernel values
-    (the level-1 scatter input); m must be a multiple of bm."""
-    return _call(q, wgt, xr, kind, inv_bw, beta, bm, interpret,
-                 reduce_sum=False, precision=precision)
+    ``wgt_ij k(q_i, xr_ij)``; m must be a multiple of bm."""
+    m, d = q.shape
+    t = xr.shape[1]
+    tt = slot_tile(bm, t, d)
+    tp = -(-t // tt) * tt
+    if tp != t:
+        wgt = jnp.pad(wgt, ((0, 0), (0, tp - t)))
+        xr = jnp.pad(xr, ((0, 0), (0, tp - t), (0, 0)))
+    body = functools.partial(_weighted_kv_kernel, kind=kind, inv_bw=inv_bw,
+                             beta=beta, precision=precision)
+    out = pl.pallas_call(
+        body,
+        grid=(m // bm, tp // tt),
+        in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((bm, tt), lambda i, j: (i, j)),
+                  pl.BlockSpec((bm, tt, d), lambda i, j: (i, j, 0))],
+        out_specs=pl.BlockSpec((bm, tt), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, tp), jnp.float32),
+        # one output tile per (query tile, slot tile), no cross-step
+        # state: both axes pipeline the gathered-row copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(q, wgt, xr)
+    return out[:, :t]

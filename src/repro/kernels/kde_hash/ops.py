@@ -157,11 +157,12 @@ def build_hash_state(x, kernel, cell_width: float | None = None,
     return state, w
 
 
-def _weighted_pass(q, xr, wgt, *, kind, inv_bw, beta, pairwise, use_pallas,
-                   interpret, bm, reduce_sum, precision="f32"):
-    """One weighted kernel-value pass: Pallas bucket kernel on the TPU
-    path (padded to a ``bm`` query multiple), the shared ``ref.rowwise_kv``
-    math elsewhere -- bitwise-identical results in interpret mode."""
+def _weighted_kv(q, xr, wgt, *, kind, inv_bw, beta, pairwise, use_pallas,
+                 interpret, bm, precision="f32"):
+    """(m, t) weighted kernel values: the Pallas bucket kernel on the
+    Pallas path (padded to a ``bm`` query multiple), the shared
+    ``ref.rowwise_kv`` math elsewhere -- bitwise-identical results in
+    interpret mode."""
     if use_pallas and kind in BUILTIN_KINDS:
         m = q.shape[0]
         rem = (-m) % bm
@@ -169,13 +170,11 @@ def _weighted_pass(q, xr, wgt, *, kind, inv_bw, beta, pairwise, use_pallas,
             q = jnp.pad(q, ((0, rem), (0, 0)))
             wgt = jnp.pad(wgt, ((0, rem), (0, 0)))
             xr = jnp.pad(xr, ((0, rem), (0, 0), (0, 0)))
-        fn = (_k.weighted_kv_sum_pallas if reduce_sum
-              else _k.weighted_kv_pallas)
-        return fn(q, wgt, xr, kind, inv_bw, beta, bm=bm,
-                  interpret=interpret, precision=precision)[:m]
-    kv = _ref.rowwise_kv(q, xr, kind, inv_bw, beta, pairwise,
-                         precision=precision) * wgt
-    return jnp.sum(kv, axis=1) if reduce_sum else kv
+        return _k.weighted_kv_pallas(q, wgt, xr, kind, inv_bw, beta, bm=bm,
+                                     interpret=interpret,
+                                     precision=precision)[:m]
+    return _ref.rowwise_kv(q, xr, kind, inv_bw, beta, pairwise,
+                           precision=precision) * wgt
 
 
 @_jit
@@ -184,33 +183,22 @@ def hashed_query(x, y, state, key, *, kind, inv_bw, beta, pairwise,
                  bm=32, precision="f32"):
     """(m,) row-sum estimates + (m,) realized NEAR eval counts + a counter
     word -- the Definition 1.1 read at O(max_bucket + num_far) evals
-    per query.  The word's status slot flags bucket truncation, out-of-range member
-    indices (JAX gathers clamp, so corruption is otherwise silent), and a
-    Horvitz-Thompson FAR sample dominating the estimate (on the jnp path
-    per element against ``REPRO_HT_FRAC``; the Pallas kernel only sees the
-    reduced sum, so there the static weight ``n/num_far`` is checked
-    against ``REPRO_HT_BOUND``)."""
+    per query.  The word's status slot flags bucket truncation,
+    out-of-range member indices (JAX gathers clamp, so corruption is
+    otherwise silent), and a Horvitz-Thompson FAR sample dominating the
+    estimate (per element, against ``REPRO_HT_FRAC``)."""
     TRACE_COUNTS["hashed_query"] += 1
     cols, xr, wgt, cnt, trunc = _ref.query_gather(x, y, state, key,
                                                   cell_width, num_far, n)
     corrupt = jnp.any((cols < 0) | (cols >= n))
-    if use_pallas and kind in BUILTIN_KINDS:
-        est = _weighted_pass(y, xr, wgt, kind=kind, inv_bw=inv_bw, beta=beta,
-                             pairwise=pairwise, use_pallas=use_pallas,
-                             interpret=interpret, bm=bm, reduce_sum=True,
-                             precision=precision)
-        heavy = jnp.asarray(num_far > 0
-                            and float(n) / num_far > _g.ht_bound())
-    else:
-        kv = _weighted_pass(y, xr, wgt, kind=kind, inv_bw=inv_bw, beta=beta,
-                            pairwise=pairwise, use_pallas=use_pallas,
-                            interpret=interpret, bm=bm, reduce_sum=False,
-                            precision=precision)
-        est = jnp.sum(kv, axis=1)
-        far = kv[:, _ref.num_exact_cols(state):]
-        heavy = (jnp.any(far > _g.ht_frac()
-                         * jnp.maximum(jnp.abs(est)[:, None], 1e-30))
-                 if num_far > 0 else jnp.asarray(False))
+    kv = _weighted_kv(y, xr, wgt, kind=kind, inv_bw=inv_bw, beta=beta,
+                      pairwise=pairwise, use_pallas=use_pallas,
+                      interpret=interpret, bm=bm, precision=precision)
+    est = jnp.sum(kv, axis=1)
+    far = kv[:, _ref.num_exact_cols(state):]
+    heavy = (jnp.any(far > _g.ht_frac()
+                     * jnp.maximum(jnp.abs(est)[:, None], 1e-30))
+             if num_far > 0 else jnp.asarray(False))
     st = _g.merge(_g.flag_if(corrupt, _g.STATE_CORRUPT),
                   _g.flag_if(jnp.any(trunc), _g.BUCKET_OVERFLOW),
                   _g.flag_if(heavy, _g.HT_HEAVY),
@@ -236,10 +224,9 @@ def _hashed_block_sums(x, src, state, key, *, kind, inv_bw, beta, pairwise,
     cols, xr, wgt, _, trunc = _ref.frontier_gather(x, src, state, key,
                                                    num_far, block_size,
                                                    num_blocks, n)
-    kv = _weighted_pass(q, xr, wgt, kind=kind, inv_bw=inv_bw, beta=beta,
-                        pairwise=pairwise, use_pallas=use_pallas,
-                        interpret=interpret, bm=bm, reduce_sum=False,
-                        precision=precision)
+    kv = _weighted_kv(q, xr, wgt, kind=kind, inv_bw=inv_bw, beta=beta,
+                      pairwise=pairwise, use_pallas=use_pallas,
+                      interpret=interpret, bm=bm, precision=precision)
     bs = _ref.scatter_block_sums(kv, cols, src, state, num_far,
                                  block_size, num_blocks)
     st = _g.merge(_g.flag_if(jnp.any((cols < 0) | (cols >= n)),
@@ -316,9 +303,11 @@ def batched_hashed_query(xa, tidx, y, state, keys, *, kind, inv_bw, beta,
     TRACE_COUNTS["batched_hashed_query"] += 1
 
     def one(ti, y_r, key_r):
-        hs = jax.tree_util.tree_map(lambda a: a[ti], state)
-        return hashed_query(xa[ti], y_r, hs, key_r, kind=kind,
-                            inv_bw=inv_bw, beta=beta, pairwise=pairwise,
+        hs = jax.tree_util.tree_map(lambda a: _sops.tenant_slice(a, ti),
+                                    state)
+        return hashed_query(_sops.tenant_slice(xa, ti), y_r, hs, key_r,
+                            kind=kind, inv_bw=inv_bw, beta=beta,
+                            pairwise=pairwise,
                             cell_width=cell_width, num_far=num_far, n=n,
                             use_pallas=use_pallas, interpret=interpret,
                             bm=bm, precision=precision)

@@ -86,39 +86,28 @@ def query_codes(y, dims, shift, cell_width: float) -> jnp.ndarray:
 
 
 def rowwise_kv(q, xr, kind: str, inv_bw: float, beta: float, pairwise=None,
-               precision: str = "f32", table=None):
+               precision: str = "f32"):
     """Per-row kernel values k(q_i, xr_i_j): q (w, d), xr (w, t, d) ->
-    (w, t), accumulated over a static d-loop.  This exact function runs
-    inside the Pallas kernel body AND in the jnp oracles, so compiled
-    (interpret) and oracle values agree bitwise.
+    (w, t), from the direct coordinate differences reduced over the
+    feature (lane) axis.  This exact function runs inside the Pallas
+    kernel body AND in the jnp oracles, so compiled (interpret) and oracle
+    values agree bitwise.
 
     ``precision="bf16"`` rounds both operand rows to bf16 (DESIGN.md §14)
-    and runs the identical f32-accumulated d-loop on the rounded values;
-    the HT weights applied downstream stay f32."""
+    and runs the identical f32 reduction on the rounded values; the HT
+    weights applied downstream stay f32."""
     if precision != "f32":
         check_precision(precision, kind, pairwise)
         q = q.astype(jnp.bfloat16).astype(jnp.float32)
         xr = xr.astype(jnp.bfloat16).astype(jnp.float32)
     if kind in _L2_KINDS:
-        d = q.shape[-1]
-        cross = jnp.zeros(xr.shape[:2], jnp.float32)
-        xx = jnp.zeros(xr.shape[:2], jnp.float32)
-        qq = jnp.zeros((q.shape[0],), jnp.float32)
-        for k in range(d):
-            c = xr[:, :, k]
-            cross = cross + q[:, k:k + 1] * c
-            xx = xx + c * c
-            qq = qq + q[:, k] * q[:, k]
-        d2 = jnp.maximum(qq[:, None] + xx - 2.0 * cross, 0.0)
+        d2 = jnp.sum(jnp.square(q[:, None, :] - xr), axis=-1)
         if precision != "f32":
-            return _finish_l2_bf16(d2, kind, inv_bw, beta, table)
+            return _finish_l2_bf16(d2, kind, inv_bw, beta)
         return _finish_l2(d2, kind, inv_bw, beta)
     if kind == "laplacian":
-        d = q.shape[-1]
-        acc = jnp.zeros(xr.shape[:2], jnp.float32)
-        for k in range(d):
-            acc = acc + jnp.abs(q[:, k:k + 1] - xr[:, :, k])
-        return jnp.exp(-acc * inv_bw)
+        d1 = jnp.sum(jnp.abs(q[:, None, :] - xr), axis=-1)
+        return jnp.exp(-d1 * inv_bw)
     return jax.vmap(lambda a, b: pairwise(a[None, :], b)[0])(q, xr)
 
 

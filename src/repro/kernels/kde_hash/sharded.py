@@ -32,7 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.ft import guards as _g
 from repro.kernels.kde_hash import ops as _ops
 from repro.kernels.kde_hash import ref as _ref
@@ -262,16 +261,20 @@ class ShardedHashTable:
                 kv = _ref.rowwise_kv(y, x_l[cols_l], sp.kind, sp.inv_bw,
                                      sp.beta, sp.pairwise)
                 part = jnp.sum(kv * wgt, axis=1)
-                return jax.lax.psum((part, cnt), axes)
+                # one psum: the NEAR counts ride along as f32 (exact, far
+                # below 2^24)
+                tot = jax.lax.psum(
+                    jnp.stack([part, cnt.astype(jnp.float32)]), axes)
+                return tot[0], tot[1].astype(cnt.dtype)
 
             def outer(*args):
                 TRACE_COUNTS["sharded_hashed_query"] += 1
-                return shard_map(body, mesh=mesh,
-                                 in_specs=(P(axes), P(axes), P(axes),
-                                           P(axes), P(), P(), P(axes),
-                                           P(), P()),
-                                 out_specs=(P(), P()),
-                                 check_vma=False)(*args)
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=(P(axes), P(axes), P(axes),
+                                               P(axes), P(), P(), P(axes),
+                                               P(), P()),
+                                     out_specs=(P(), P()),
+                                     check_vma=False)(*args)
             _PROGRAM_CACHE[sp] = jax.jit(outer)
         return _PROGRAM_CACHE[sp]
 
@@ -338,11 +341,11 @@ class ShardedHashTable:
 
             def outer(*args):
                 TRACE_COUNTS["sharded_hash_patch"] += 1
-                return shard_map(body, mesh=mesh,
-                                 in_specs=(P(axes), P(axes), P(axes),
-                                           P(axes)) + (P(),) * 9,
-                                 out_specs=(P(axes),) * 4,
-                                 check_vma=False)(*args)
+                return jax.shard_map(body, mesh=mesh,
+                                     in_specs=(P(axes), P(axes), P(axes),
+                                               P(axes)) + (P(),) * 9,
+                                     out_specs=(P(axes),) * 4,
+                                     check_vma=False)(*args)
             _PROGRAM_CACHE[full] = jax.jit(outer)
         return _PROGRAM_CACHE[full]
 

@@ -6,24 +6,24 @@ read of the depth-2 sampler, DESIGN.md §2).
 
 Tiling: q tiles (bm, d) and x tiles (bn, d) stream HBM->VMEM; for L2 kernels
 (gaussian / exponential / rational quadratic) the pairwise distances use the
-MXU via the ||q||^2 + ||x||^2 - 2 q.x factorization; the L1 (laplacian)
-kernel has no matmul form, so |q - x| is accumulated over d-chunks on the VPU
-with a (bm, bn) accumulator resident in VMEM.
+MXU via the ||q||^2 + ||x||^2 - 2 q.x factorization (f32 operands at full
+f32 contract precision); the L1 (laplacian) kernel has no matmul form, so
+|q - x| is accumulated over d-chunks on the VPU.
 
-Block sizes default to MXU-aligned 128 lanes; the row accumulator lives in a
-VMEM scratch and is flushed on the last j-step (revisiting output pattern).
-Both grids carry ``dimension_semantics`` so the Mosaic pipeliner
-double-buffers the HBM->VMEM tile copies: the query axis is "parallel"
-everywhere; the x-block axis is "arbitrary" for the rowsum (its VMEM
-accumulator is a cross-j carry) and "parallel" for the blocksum (each cell
-owns its output block).
+Every block the chip sees is a lane-dense 2-D tile (the Mosaic (8, 128)
+rule): per-row results live in ``(bm, LANES)`` tiles holding the value
+broadcast across the lanes (the wrappers read lane 0), and per-block sums
+are written into ``(bm, G)`` output tiles that cover G consecutive x-blocks
+(``lane_group``): grid step j writes column ``j % G`` of the tile that
+block index ``j // G`` revisits, so the x-block axis is "arbitrary"
+(sequential revisit) and the query axis "parallel".
 
 ``precision="bf16"`` (DESIGN.md §14) rounds both operand tiles to bf16 --
-halving the staged bytes, which is what a bandwidth-bound sweep buys from
-mixed precision -- while the distance accumulation (MXU ``preferred_element_
-type``), the kernel transform, and every downstream sum stay f32.  The norm
-terms are recomputed in f32 from the *rounded* coordinates so the bf16 path
-is a pure function of the bf16 operands (bitwise-matched by the jnp refs).
+halving the MXU operand bytes -- while the distance accumulation (MXU
+``preferred_element_type``), the kernel transform, and every downstream sum
+stay f32.  The norm terms are recomputed in f32 from the *rounded*
+coordinates so the bf16 path is a pure function of the bf16 operands
+(bitwise-matched by the jnp refs).
 """
 from __future__ import annotations
 
@@ -34,34 +34,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kde_sampler.ref import (_finish_l2_bf16, bf16_exp_table,
-                                           check_precision)
+from repro.kernels.kde_sampler.ref import _finish_l2_bf16, check_precision
 
 _L2_KINDS = ("gaussian", "exponential", "rational_quadratic")
-_EXP_KINDS = ("gaussian", "exponential")
+
+#: lane width of a TPU vreg: per-row outputs are (bm, LANES) tiles
+LANES = 128
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def needs_exp_table(kind: str, precision: str) -> bool:
-    """True when the bf16 finisher gathers from the exp table -- Pallas
-    callers must then stream the table in as an input (a closed-over
-    numpy constant is rejected by ``pallas_call``)."""
-    return precision != "f32" and kind in _EXP_KINDS
+def lane_group(num_blocks: int) -> tuple[int, int]:
+    """(G, padded block count) for an (m, num_blocks) per-block output:
+    one (bm, G) tile covers G consecutive x-blocks, G = num_blocks when it
+    fits one lane tile, else LANES with the block axis padded to a LANES
+    multiple (the pad columns stay 0 and are sliced off)."""
+    if num_blocks <= LANES:
+        return num_blocks, num_blocks
+    return LANES, -(-num_blocks // LANES) * LANES
 
 
-def exp_table_operand() -> jnp.ndarray:
-    """The (65536,) f32 exp table as a device operand for Pallas calls."""
-    return jnp.asarray(bf16_exp_table())
-
-
-def exp_table_spec(index_map) -> pl.BlockSpec:
-    """Whole-table BlockSpec with a constant index map, so the pipeliner
-    keeps one resident copy instead of restaging it per grid step."""
-    return pl.BlockSpec((65536,), index_map)
+def put_column(o_ref, col, s):
+    """Write the (bm, 1) column ``s`` into lane ``col`` of the (bm, G)
+    output tile -- a lane-masked select, so every store is a full tile."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+    o_ref[...] = jnp.where(lane == col, s, o_ref[...])
 
 
 def _tile_kernel_values(q, x, kind: str, inv_bw: float, beta: float,
-                        d_chunk: int = 128, precision: str = "f32",
-                        table=None):
+                        d_chunk: int = 128, precision: str = "f32"):
     """(bm, bn) kernel values for one (q-tile, x-tile) pair."""
     if precision != "f32":
         check_precision(precision, kind, None)
@@ -74,11 +75,12 @@ def _tile_kernel_values(q, x, kind: str, inv_bw: float, beta: float,
         cross = jax.lax.dot_general(qb, xb, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
         d2 = jnp.maximum(qq + xx - 2.0 * cross, 0.0)
-        return _finish_l2_bf16(d2, kind, inv_bw, beta, table)
+        return _finish_l2_bf16(d2, kind, inv_bw, beta)
     if kind in _L2_KINDS:
         qq = jnp.sum(q * q, axis=1, keepdims=True)
         xx = jnp.sum(x * x, axis=1, keepdims=True).T
         cross = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                                    precision=_HIGHEST,
                                     preferred_element_type=jnp.float32)
         d2 = jnp.maximum(qq + xx - 2.0 * cross, 0.0)
         if kind == "gaussian":
@@ -98,14 +100,8 @@ def _tile_kernel_values(q, x, kind: str, inv_bw: float, beta: float,
     return jnp.exp(-acc * inv_bw)
 
 
-def _rowsum_kernel(q_ref, x_ref, *rest, kind, inv_bw, beta, precision,
-                   has_table):
-    if has_table:
-        t_ref, o_ref, acc_ref = rest
-        table = t_ref[...]
-    else:
-        o_ref, acc_ref = rest
-        table = None
+def _rowsum_kernel(q_ref, x_ref, o_ref, acc_ref, *, kind, inv_bw, beta,
+                   precision):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -113,25 +109,25 @@ def _rowsum_kernel(q_ref, x_ref, *rest, kind, inv_bw, beta, precision,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv = _tile_kernel_values(q_ref[...], x_ref[...], kind, inv_bw, beta,
-                             precision=precision, table=table)
-    acc_ref[...] += jnp.sum(kv, axis=1)
+                             precision=precision)
+    acc_ref[...] += jnp.sum(kv, axis=1, keepdims=True)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
         o_ref[...] = acc_ref[...]
 
 
-def _blocksum_kernel(q_ref, x_ref, *rest, kind, inv_bw, beta, precision,
-                     has_table):
-    if has_table:
-        t_ref, o_ref = rest
-        table = t_ref[...]
-    else:
-        (o_ref,) = rest
-        table = None
+def _blocksum_kernel(q_ref, x_ref, o_ref, *, kind, inv_bw, beta, precision,
+                     group):
+    col = pl.program_id(1) % group
+
+    @pl.when(col == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
     kv = _tile_kernel_values(q_ref[...], x_ref[...], kind, inv_bw, beta,
-                             precision=precision, table=table)
-    o_ref[...] = jnp.sum(kv, axis=1, keepdims=True)
+                             precision=precision)
+    put_column(o_ref, col, jnp.sum(kv, axis=1, keepdims=True))
 
 
 def rowsum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
@@ -141,29 +137,23 @@ def rowsum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
     """q (m, d), x (n, d) -> (m,); m, n must be multiples of bm, bn."""
     m, d = q.shape
     n = x.shape[0]
-    has_table = needs_exp_table(kind, precision)
     body = functools.partial(_rowsum_kernel, kind=kind, inv_bw=inv_bw,
-                             beta=beta, precision=precision,
-                             has_table=has_table)
-    in_specs = [pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i, j: (j, 0))]
-    operands = [q, x]
-    if has_table:
-        in_specs.append(exp_table_spec(lambda i, j: (0,)))
-        operands.append(exp_table_operand())
-    return pl.pallas_call(
+                             beta=beta, precision=precision)
+    out = pl.pallas_call(
         body,
         grid=(m // bm, n // bn),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm,), jnp.float32)],
+        in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((bn, d), lambda i, j: (j, 0))],
+        out_specs=pl.BlockSpec((bm, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, LANES), jnp.float32)],
         # the row accumulator is a cross-j VMEM carry, so the x-block axis
         # must stay sequential; query tiles double-buffer in parallel
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(q, x)
+    return out[:, 0]
 
 
 def blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
@@ -172,27 +162,20 @@ def blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
                     precision: str = "f32") -> jnp.ndarray:
     """q (m, d), x (n, d) -> (m, n/bn) per-block sums (level-1 read)."""
     m, d = q.shape
-    n = x.shape[0]
-    nb = n // bn
-    has_table = needs_exp_table(kind, precision)
+    nb = x.shape[0] // bn
+    group, nbp = lane_group(nb)
     body = functools.partial(_blocksum_kernel, kind=kind, inv_bw=inv_bw,
-                             beta=beta, precision=precision,
-                             has_table=has_table)
-    in_specs = [pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i, j: (j, 0))]
-    operands = [q, x]
-    if has_table:
-        in_specs.append(exp_table_spec(lambda i, j: (0,)))
-        operands.append(exp_table_operand())
-    return pl.pallas_call(
+                             beta=beta, precision=precision, group=group)
+    out = pl.pallas_call(
         body,
         grid=(m // bm, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, nb), jnp.float32),
-        # no cross-step state: every (i, j) cell writes its own output
-        # block, so both axes pipeline with double-buffered tile copies
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((bn, d), lambda i, j: (j, 0))],
+        out_specs=pl.BlockSpec((bm, group), lambda i, j: (i, j // group)),
+        out_shape=jax.ShapeDtypeStruct((m, nbp), jnp.float32),
+        # consecutive x-blocks revisit one (bm, G) output tile
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(q, x)
+    return out[:, :nb]

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.kernels_fn import Kernel
+from repro.kernels import platform as _platform
 from repro.kernels import tuning as _tuning
 from repro.kernels.kde_rowsum import kernel as _k
 from repro.kernels.kde_rowsum import ref as _ref
@@ -64,8 +65,7 @@ def kde_rowsum(q, x, kernel: Kernel, bm: int | None = None,
                bn: int | None = None, interpret: bool | None = None,
                precision: str = "f32") -> jnp.ndarray:
     """KDE oracle: (m,) row sums of the kernel matrix block k(q, x)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _platform.interpret_mode(interpret)
     beta = getattr(kernel, "beta", 1.0)
     inv_bw = 1.0 / kernel.bandwidth
     q = jnp.asarray(q, jnp.float32)
@@ -92,8 +92,7 @@ def kde_blocksum(q, x, kernel: Kernel, bm: int = 128, bn: int = 256,
     """Level-1 read: (m, ceil(n/bn)) per-block kernel sums.  ``bn`` is the
     semantic level-1 block size (it fixes the output width), so it is
     never autotuned."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _platform.interpret_mode(interpret)
     inv_bw = 1.0 / kernel.bandwidth
     return _blocksum(jnp.asarray(q, jnp.float32), jnp.asarray(x, jnp.float32),
                      kernel.name, inv_bw, getattr(kernel, "beta", 1.0), bm,

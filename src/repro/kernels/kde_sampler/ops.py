@@ -1,8 +1,9 @@
 """Device-resident fused depth-2 neighbor sampling engine (DESIGN.md §3).
 
 One walk step = ONE compiled program: level-1 masked block sums (Pallas on
-TPU, jnp sweep elsewhere), Gumbel-max block draw, level-2 exact in-block
-row, and the in-block categorical draw -- no host sync between stages.
+TPU, jnp sweep elsewhere -- ``kernels.platform`` decides), Gumbel-max
+block draw, level-2 exact in-block row, and the in-block categorical
+draw -- no host sync between stages.
 ``jax.random`` keys drive all randomness, so every path is jit-compatible
 and reproducible.
 
@@ -102,10 +103,6 @@ def _jit(fn):
     """jit with the subset of _STATIC names this function actually takes."""
     names = tuple(p for p in inspect.signature(fn).parameters if p in _STATIC)
     return jax.jit(fn, static_argnames=names)
-
-
-def default_use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------------- #
@@ -999,13 +996,21 @@ def triangle_edge_scan(x, x_sq, u, v, degs, keys, hstate=None, *, kind,
 # to static buckets by the serving layer, which bounds recompiles to one
 # program per (tenant signature, op, bucket) group.
 # --------------------------------------------------------------------- #
+def tenant_slice(a, ti):
+    """One request's slice of a stacked arena leaf.  Runs under vmap, so
+    ``ti`` is a traced per-request scalar and ``a[ti]`` is a batched
+    gather -- one copy of the tenant's rows per request.  A one-tenant
+    arena (static leading dim 1) is read as ``a[0]`` instead: unbatched,
+    so the request lanes share the tenant's rows without copying them."""
+    return a[0] if a.shape[0] == 1 else a[ti]
+
+
 def _tenant(xa, xa_sq, hstate, ti):
-    """Gather one request's tenant slice out of the stacked arena.  Runs
-    under vmap, so ``ti`` is a traced per-request scalar and the hash
-    state (when present) is gathered leaf-wise from the stacked pytree."""
-    hs = (jax.tree_util.tree_map(lambda a: a[ti], hstate)
+    """Gather one request's tenant slice out of the stacked arena (the
+    hash state, when present, leaf-wise from the stacked pytree)."""
+    hs = (jax.tree_util.tree_map(lambda a: tenant_slice(a, ti), hstate)
           if hstate is not None else None)
-    return xa[ti], xa_sq[ti], hs
+    return tenant_slice(xa, ti), tenant_slice(xa_sq, ti), hs
 
 
 @_jit
@@ -1108,7 +1113,7 @@ def batched_kde_query(xa, xa_sq, tidx, y, keys, *, kind, inv_bw, beta,
     TRACE_COUNTS["batched_kde_query"] += 1
 
     def one(ti, y_r, key_r):
-        x, x_sq = xa[ti], xa_sq[ti]
+        x, x_sq, _ = _tenant(xa, xa_sq, None, ti)
         if exact:
             bs, cw = exact_block_sums(y_r, x, x_sq, kind=kind,
                                       inv_bw=inv_bw, beta=beta,

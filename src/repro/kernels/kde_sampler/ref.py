@@ -59,9 +59,10 @@ PRECISIONS = ("f32", "bf16")
 # k = exp(-c d2) the per-value relative error is ~ Δd2 = 2^-7 d2.  Terms
 # with d2 large enough to push that bound past ~6% (d2 > 8) contribute
 # k < 3e-4 of the row mass, so the row-sum relative error is bounded by
-# the d2 <~ 8 envelope: 8 * 2^-7 = 2^-4.  (The bf16 exp table adds only
-# 2^-9 on top.)  Measured on gaussian n=262144 d=16: 4.1e-2 max over 256
-# queries -- inside this bound, outside any tighter one.
+# the d2 <~ 8 envelope: 8 * 2^-7 = 2^-4.  (Rounding the exp argument to
+# bf16 adds only 2^-9 on top.)  Measured on gaussian n=262144 d=16:
+# 4.1e-2 max over 256 queries -- inside this bound, outside any tighter
+# one.
 # tests/test_precision.py pins estimator outputs to 2 * this bound.
 BF16_REL_ERR = 2.0 ** -4
 
@@ -71,42 +72,14 @@ BF16_REL_ERR = 2.0 ** -4
 # on the bf16 path too.
 _FAR_OFFSET = 1.0e30
 
-_EXP_TABLE = None
+def exp_bf16(y):
+    """exp() of ``y`` after rounding it to bf16, evaluated in f32.
 
-
-def bf16_exp_table():
-    """(65536,) f32 table of exp() over every bfloat16 bit pattern.
-
-    A bf16 argument has only 2^16 distinct values, so exp on a bf16-rounded
-    argument is an exact table gather -- one f32 load instead of a
-    transcendental per element, which is what makes the bf16 sweep
-    bandwidth-bound instead of exp-bound on the host backend.  -inf maps
-    to 0.0 and NaN patterns stay NaN (corruption propagates, the status
-    guards still fire).  Built lazily once per process.
+    The bf16 path's transcendental takes the rounded argument (so it is a
+    pure function of a bf16 value) and runs the native f32 ``exp`` --
+    the same call inside the Pallas kernel bodies and in the jnp refs.
     """
-    global _EXP_TABLE
-    if _EXP_TABLE is None:
-        import numpy as np
-        with np.errstate(over="ignore", invalid="ignore"):
-            args = (np.arange(65536, dtype=np.uint32) << 16).view(np.float32)
-            # cache as NUMPY: a jnp constant materialized inside a trace
-            # would be a tracer, and caching a tracer across traces leaks
-            _EXP_TABLE = np.exp(args.astype(np.float64)).astype(np.float32)
-    return _EXP_TABLE
-
-
-def exp_bf16(y, table=None):
-    """exp() of ``y`` after rounding it to bf16, as an exact table read.
-
-    ``table`` lets Pallas kernel bodies pass the table in as a VMEM ref
-    value -- a closed-over numpy array would be a captured constant, which
-    ``pallas_call`` rejects.  jnp callers leave it None.
-    """
-    yb = y.astype(jnp.bfloat16)
-    bits = jax.lax.bitcast_convert_type(yb, jnp.uint16).astype(jnp.int32)
-    if table is None:
-        table = jnp.asarray(bf16_exp_table())
-    return jnp.take(table, bits)
+    return jnp.exp(y.astype(jnp.bfloat16).astype(jnp.float32))
 
 
 def check_precision(precision: str, kind: str, pairwise=None) -> None:
@@ -120,16 +93,15 @@ def check_precision(precision: str, kind: str, pairwise=None) -> None:
             f"(gaussian / exponential / rational_quadratic); got {kind!r}")
 
 
-def _finish_l2_bf16(d2, kind: str, inv_bw: float, beta: float, table=None):
-    """L2-kind finisher of the bf16 path: f32 d2 in, table-exp out.  This
-    exact function runs inside the Pallas kernel bodies AND the jnp refs,
-    so interpret-mode bf16 runs match the oracles bitwise.  Pallas bodies
-    pass the exp table as a streamed input via ``table``."""
+def _finish_l2_bf16(d2, kind: str, inv_bw: float, beta: float):
+    """L2-kind finisher of the bf16 path: f32 d2 in, ``exp_bf16`` out.
+    This exact function runs inside the Pallas kernel bodies AND the jnp
+    refs, so interpret-mode bf16 runs match the oracles bitwise."""
     d2 = jnp.maximum(d2, 0.0)
     if kind == "gaussian":
-        return exp_bf16(-d2 * (inv_bw * inv_bw), table)
+        return exp_bf16(-d2 * (inv_bw * inv_bw))
     if kind == "exponential":
-        return exp_bf16(-jnp.sqrt(d2) * inv_bw, table)
+        return exp_bf16(-jnp.sqrt(d2) * inv_bw)
     return (1.0 + d2 * (inv_bw * inv_bw)) ** (-beta)
 
 
@@ -157,7 +129,7 @@ def kv_block_sums_bf16(q, x, kind: str, inv_bw: float, beta: float,
     The bandwidth-optimal level-1 sweep: the dataset is rounded to bf16,
     pre-transposed into (tile, d, tile_cols) GEMM layout ONCE, and a
     ``lax.scan`` walks the column tiles -- each step is one
-    (m, d) x (d, tile_cols) bf16 GEMM with an f32 accumulator, the table
+    (m, d) x (d, tile_cols) bf16 GEMM with an f32 accumulator, the bf16
     exp, and an in-register per-block reduction.  Peak live memory is the
     (m, tile_cols) f32 value tile instead of the dense (m, n) matrix, so
     the sweep streams the dataset at memory bandwidth.  The tail is padded
@@ -211,7 +183,10 @@ def kv_matrix(q, x, x_sq, kind: str, inv_bw: float, beta: float,
         return kv_matrix_bf16(q, x, kind, inv_bw, beta)
     if kind in _L2_KINDS:
         qq = jnp.sum(q * q, axis=1, keepdims=True)
-        d2 = qq + x_sq[None, :] - 2.0 * (q @ x.T)
+        # full f32 contract precision (a TPU's default f32 dot is one
+        # bf16 pass -- see kernels_fn._sq_dists)
+        d2 = qq + x_sq[None, :] - 2.0 * jnp.matmul(
+            q, x.T, precision=jax.lax.Precision.HIGHEST)
         return _finish_l2(d2, kind, inv_bw, beta)
     if kind == "laplacian":
         # cap the (m, n, d) broadcast at ~1 GiB of f32 (static unroll)

@@ -58,9 +58,9 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.ft import guards as _g
 from repro.kernels.kde_rowsum.ops import _PAD_OFFSET
 from repro.kernels.kde_sampler import ops as _ops
@@ -92,13 +92,13 @@ def collective_counts(fn, *args, **kwargs):
             if any(name.startswith(c) for c in _COLLECTIVES):
                 acc[name] = acc.get(name, 0) + 1
             for v in eqn.params.values():
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, ClosedJaxpr):
                     visit(v.jaxpr)
                 elif hasattr(v, "eqns"):
                     visit(v)
                 elif isinstance(v, (tuple, list)):
                     for w in v:
-                        if isinstance(w, jax.core.ClosedJaxpr):
+                        if isinstance(w, ClosedJaxpr):
                             visit(w.jaxpr)
     visit(jaxpr.jaxpr)
     acc["psum_total"] = sum(v for k, v in acc.items() if k.startswith("psum"))
@@ -220,17 +220,23 @@ class _EngineSpec:
         nb_l, pin = _ref.level2_draw(kv, live, jnp.minimum(gcols, self.n - 1),
                                      jax.random.uniform(k_in, (w,)))
         qnum = s_b * pin
-        oh_f = (jnp.arange(self.num_shards) == pidx).astype(jnp.float32)
-        oh_i = (jnp.arange(self.num_shards) == pidx).astype(jnp.int32)
-        t_all, q_all, nb_all = jax.lax.psum(
-            (t_l[:, None] * oh_f[None, :], qnum[:, None] * oh_f[None, :],
-             nb_l[:, None] * oh_i[None, :]), self.axes)
+        # ONE f32 array carries the whole one-hot payload (psum binds once
+        # per array): the neighbor travels as its in-shard offset, exact in
+        # f32 because shard_size < 2^24 (checked at construction), and
+        # adding the other shards' zeros leaves every entry bit-exact
+        local = (nb_l - pidx * self.shard_size).astype(jnp.float32)
+        oh = (jnp.arange(self.num_shards) == pidx).astype(jnp.float32)
+        payload = jnp.stack([t_l, qnum, local], axis=-1)          # (w, 3)
+        allp = jax.lax.psum(payload[:, None, :] * oh[None, :, None],
+                            self.axes)                            # (w, P, 3)
+        t_all, q_all = allp[..., 0], allp[..., 1]
         ct = jnp.cumsum(t_all, axis=1)
         tot = ct[:, -1]
         u0 = jax.random.uniform(k_shard, (w,))
         owner = jnp.sum((u0 * tot)[:, None] > ct, axis=1).clip(
             0, self.num_shards - 1)
-        nb = jnp.take_along_axis(nb_all, owner[:, None], axis=1)[:, 0]
+        nb = (owner * self.shard_size + jnp.take_along_axis(
+            allp[..., 2], owner[:, None], axis=1)[:, 0].astype(jnp.int32))
         prob = jnp.take_along_axis(q_all, owner[:, None], axis=1)[:, 0] \
             / jnp.maximum(tot, 1e-30)
         num_real = -(-self.n // self.block_size)
@@ -288,6 +294,9 @@ class ShardedBlocks:
         bs = int(block_size)
         per = -(-n // num_shards)                             # ceil(n / P)
         shard_size = -(-per // bs) * bs
+        if shard_size >= 1 << 24:
+            raise ValueError(f"shard_size {shard_size} >= 2^24: the draw "
+                             "payload carries in-shard offsets in f32")
         self.spec = _EngineSpec(
             mesh=mesh, axes=axes, num_shards=num_shards, n=n, d=d,
             block_size=bs, shard_size=shard_size,
@@ -334,8 +343,8 @@ class ShardedBlocks:
             # check_vma=False: the replication checker cannot follow a
             # psum-in-scan-body carry; replication of the outputs is pinned
             # by the ref-oracle tests instead.
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)(*args)
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)(*args)
         return jax.jit(outer)
 
     def _program(self, key, factory):
@@ -805,8 +814,8 @@ def make_kde_query(mesh: Mesh, kernel, data_axes: Sequence[str] = ("data",)):
         part = jnp.sum(kernel.pairwise(y, x_l), axis=1)
         return jax.lax.psum(part, axes)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(), P(axes)),
-                             out_specs=P()))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P(axes)),
+                                 out_specs=P()))
 
 
 def make_block_sums(mesh: Mesh, kernel, num_blocks_per_shard: int,
@@ -846,12 +855,12 @@ def make_block_sums(mesh: Mesh, kernel, num_blocks_per_shard: int,
         return jnp.where(real[None, :],
                          jnp.maximum(sums, _ref.BLOCK_SUM_FLOOR), 0.0)
 
-    raw = jax.jit(shard_map(lambda y, x_l: local(y, x_l, None), mesh=mesh,
-                            in_specs=(P(), P(axes)),
-                            out_specs=P(None, axes)))
-    masked = jax.jit(shard_map(local, mesh=mesh,
-                               in_specs=(P(), P(axes), P()),
-                               out_specs=P(None, axes)))
+    raw = jax.jit(jax.shard_map(lambda y, x_l: local(y, x_l, None), mesh=mesh,
+                                in_specs=(P(), P(axes)),
+                                out_specs=P(None, axes)))
+    masked = jax.jit(jax.shard_map(local, mesh=mesh,
+                                   in_specs=(P(), P(axes), P()),
+                                   out_specs=P(None, axes)))
 
     def f(y, x, own=None):
         if own is None:
@@ -871,8 +880,8 @@ def make_degree_ring(mesh: Mesh, kernel,
     for a in axes:
         size *= int(mesh.shape[a])
     body = _ring_degrees_body(kernel, axes, size)
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(axes),),
-                             out_specs=P(axes)))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axes),),
+                                 out_specs=P(axes)))
 
 
 # --------------------------------------------------------------------- #
@@ -919,10 +928,10 @@ def _noisy_power_program(mesh: Mesh, axes, num_samples: int, cols_per: int):
 
     def outer(ksub_sh, v0, keys):
         TRACE_COUNTS["sharded_noisy_power_scan"] += 1
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(None, axes), P(), P()),
-                         out_specs=(P(), P(), P()),
-                         check_vma=False)(ksub_sh, v0, keys)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(None, axes), P(), P()),
+                             out_specs=(P(), P(), P()),
+                             check_vma=False)(ksub_sh, v0, keys)
     return jax.jit(outer)
 
 

@@ -29,6 +29,9 @@ programs, with p50/p99 request latency and throughput in the metrics
 line:
 
   python -m repro.launch.serve --serve-tenants 4 --requests 16 --ticks 4
+
+The same loop drives ``chip_smoke.py`` at deployment size (``--points``,
+``--dim``; ``build_servable`` / ``serve_mix`` are its entry points).
 """
 from __future__ import annotations
 
@@ -43,6 +46,7 @@ import numpy as np
 
 from repro.configs.base import ShapeConfig, get_config, get_reduced
 from repro.data.pipeline import make_batch, token_split
+from repro.launch.cache import use_compile_cache
 from repro.models import transformer as T
 from repro.obs import export as _export
 from repro.obs import metrics as _metrics
@@ -140,86 +144,122 @@ def run_graph_stream(args, trace=None) -> int:
     return 3 if err is not None else 0
 
 
-def run_multi_tenant(args) -> int:
-    """Multi-tenant batched serving loop (DESIGN.md §13): S tenants with
-    mixed estimator configs, ``--requests`` concurrent mixed requests per
-    tick drained into padded batch groups.  Reports p50/p99 submit ->
-    completion latency and served-requests/s (steady-state: the first
-    tick warms every (op, bucket) program off-clock).  Exit codes: 0
-    clean; 3 when ``REPRO_CHECKS=1`` turned a request's status flags into
-    a per-request error."""
-    from repro.core.kernels_fn import gaussian
+def build_servable(points, kernel, tenant_opts, max_resident: int = 4,
+                   seed: int = 0):
+    """A :class:`KernelGraphServable` with one tenant ``t{i}`` per point
+    set, tenant i configured by ``tenant_opts[i]`` (``add_tenant``
+    keywords: ``level1``, ``exact_blocks``, ``hash_opts``, ...).  Each
+    tenant's capacity is its point count (no insert headroom: the serving
+    mix does not mutate)."""
     from repro.core.serving import KernelGraphServable
+    srv = KernelGraphServable(max_resident=int(max_resident))
+    names = []
+    for i, (x, opts) in enumerate(zip(points, tenant_opts)):
+        names.append(f"t{i}")
+        srv.add_tenant(names[-1], x, kernel, capacity=int(x.shape[0]),
+                       seed=seed + i, **opts)
+    return srv, names
+
+
+def submit_mix(srv, names, rng, tick: int, requests: int, seed: int):
+    """One tick's mixed load: ``requests`` requests rotating over the
+    tenants and over the ops sample (16 sources), query (8 dataset rows),
+    walk (8 starts, length 4) and prob_of (16 pairs).  Returns the
+    :class:`Request` handles."""
+    reqs = []
+    for r in range(requests):
+        tn = names[(r + tick) % len(names)]
+        n = srv.dataset(tn).num_live
+        op = ("sample", "query", "walk", "prob_of")[r % 4]
+        rs = seed + 1000 * tick + r
+        if op == "sample":
+            reqs.append(srv.submit(tn, "sample", seed=rs,
+                                   src=rng.integers(0, n, size=16)))
+        elif op == "query":
+            rows = jnp.asarray(rng.integers(0, n, size=8))
+            reqs.append(srv.submit(
+                tn, "query", seed=rs,
+                y=np.asarray(srv.dataset(tn).x_pad[rows])))
+        elif op == "walk":
+            reqs.append(srv.submit(tn, "walk", seed=rs, length=4,
+                                   starts=rng.integers(0, n, size=8)))
+        else:
+            reqs.append(srv.submit(tn, "prob_of", seed=rs,
+                                   src=rng.integers(0, n, size=16),
+                                   dst=rng.integers(0, n, size=16)))
+    return reqs
+
+
+def serve_mix(srv, names, *, requests: int, ticks: int, seed: int = 0):
+    """Warm-up tick (compiles every (op, bucket) group program; timed
+    separately as ``warm_s``), then ``ticks`` measured ticks of
+    :func:`submit_mix` load.  Every tick ends with the results on the
+    host, so ``wall_s`` is fenced.  Returns the run summary, including
+    the measured :class:`Request` objects under ``reqs``."""
+    rng = np.random.default_rng(seed)
+    t0 = time.perf_counter()
+    warm = submit_mix(srv, names, rng, 0, requests, seed)
+    srv.tick()
+    warm_s = time.perf_counter() - t0
+    reqs, stale = [], 0
+    evals0 = srv.device_counters["evals"]
+    t0 = time.perf_counter()
+    for tick in range(1, ticks + 1):
+        batch = submit_mix(srv, names, rng, tick, requests, seed)
+        stale += srv.tick()["stale"]
+        reqs.extend(batch)
+    wall_s = time.perf_counter() - t0
+    failed = sum(r.error is not None for r in reqs)
+    return dict(warm_s=warm_s, wall_s=wall_s, reqs=reqs, warm_reqs=warm,
+                served=len(reqs) - failed, failed=failed, stale=stale,
+                realized_evals=srv.device_counters["evals"] - evals0)
+
+
+def run_multi_tenant(args) -> int:
+    """Multi-tenant batched serving loop (DESIGN.md §13): S tenants of
+    ``--points`` x ``--dim`` points with mixed estimator configs,
+    ``--requests`` concurrent mixed requests per tick drained into padded
+    batch groups.  Reports p50/p99 submit -> completion latency and
+    served-requests/s (steady-state: the first tick warms every (op,
+    bucket) program off-clock).  Exit codes: 0 clean; 3 when any request
+    failed (under ``REPRO_CHECKS=1`` a request's status flags become a
+    per-request error)."""
+    from repro.core.kernels_fn import gaussian
 
     if args.telemetry:
         _metrics.enable()
     S, R = int(args.serve_tenants), int(args.requests)
-    n, d = 2048, 8
+    n, d = int(args.points), int(args.dim)
     rng = np.random.default_rng(args.seed)
-    srv = KernelGraphServable(max_resident=int(args.max_resident))
-    for i in range(S):
-        x = rng.normal(size=(n, d)).astype(np.float32) + 0.1 * i
-        level1 = "hash" if (args.level1 == "hash" and i % 2 == 1) else \
-            "blocked"
-        # one shared kernel config: tenants with equal static signatures
-        # stack into the same batch group (the cross-tenant win)
-        srv.add_tenant(f"t{i}", x, gaussian(1.0), level1=level1,
-                       seed=args.seed + i)
-
-    def submit_mix(tick):
-        reqs = []
-        for r in range(R):
-            tn = f"t{(r + tick) % S}"
-            op = ("sample", "query", "walk", "prob_of")[r % 4]
-            seed = args.seed + 1000 * tick + r
-            if op == "sample":
-                reqs.append(srv.submit(tn, "sample", seed=seed,
-                                       src=rng.integers(0, n, size=16)))
-            elif op == "query":
-                reqs.append(srv.submit(
-                    tn, "query", seed=seed,
-                    y=rng.normal(size=(8, d)).astype(np.float32)))
-            elif op == "walk":
-                reqs.append(srv.submit(tn, "walk", seed=seed, length=4,
-                                       starts=rng.integers(0, n, size=8)))
-            else:
-                reqs.append(srv.submit(tn, "prob_of", seed=seed,
-                                       src=rng.integers(0, n, size=16),
-                                       dst=rng.integers(0, n, size=16)))
-        return reqs
-
-    submit_mix(0)
-    srv.tick()                       # warmup: compiles every group shape
-    lat = []
-    failed = stale = 0
+    points = [rng.normal(size=(n, d)).astype(np.float32) + 0.1 * i
+              for i in range(S)]
+    # one shared kernel config: tenants with equal static signatures
+    # stack into the same batch group (the cross-tenant win)
+    opts = [dict(level1="hash" if (args.level1 == "hash" and i % 2 == 1)
+                 else "blocked") for i in range(S)]
+    srv, names = build_servable(points, gaussian(1.0), opts,
+                                max_resident=args.max_resident,
+                                seed=args.seed)
+    run = serve_mix(srv, names, requests=R, ticks=args.ticks,
+                    seed=args.seed)
     per_tenant: dict = {}
-    t0 = time.perf_counter()
-    for tick in range(1, args.ticks + 1):
-        reqs = submit_mix(tick)
-        stale += srv.tick()["stale"]
-        for r in reqs:
-            lat.append(r.latency)
-            pt = per_tenant.setdefault(
-                r.tenant, dict(served=0, failed=0, lat_ms=[]))
-            pt["lat_ms"].append(1e3 * r.latency)
-            if r.error is None:
-                pt["served"] += 1
-            else:
-                pt["failed"] += 1
-                failed += 1
-    wall = time.perf_counter() - t0
-    lat_ms = 1e3 * np.asarray(lat)
+    for r in run["reqs"]:
+        pt = per_tenant.setdefault(r.tenant,
+                                   dict(served=0, failed=0, lat_ms=[]))
+        pt["lat_ms"].append(1e3 * r.latency)
+        pt["served" if r.error is None else "failed"] += 1
+    lat_ms = 1e3 * np.asarray([r.latency for r in run["reqs"]])
     rep = srv.report()
-    served = args.ticks * R - failed
-    print(f"[serve] multi-tenant S={S} R={R}/tick ticks={args.ticks} "
-          f"max_resident={args.max_resident}")
+    served, failed, wall = run["served"], run["failed"], run["wall_s"]
+    print(f"[serve] multi-tenant S={S} n={n} d={d} R={R}/tick "
+          f"ticks={args.ticks} max_resident={args.max_resident}")
     print(f"[serve] p50 {np.percentile(lat_ms, 50):.1f} ms, "
           f"p99 {np.percentile(lat_ms, 99):.1f} ms, "
           f"{served / max(wall, 1e-9):.1f} req/s "
           f"(admissions={rep['admissions']} evictions={rep['evictions']})")
     _emit_metrics(dict(
         mode="multi-tenant", tenants=S, requests_per_tick=R,
-        ticks=args.ticks, served=served, failed=failed, stale=stale,
+        ticks=args.ticks, served=served, failed=failed, stale=run["stale"],
         p50_ms=round(float(np.percentile(lat_ms, 50)), 3),
         p99_ms=round(float(np.percentile(lat_ms, 99)), 3),
         throughput_rps=round(served / max(wall, 1e-9), 2),
@@ -268,6 +308,10 @@ def main(argv=None) -> int:
                          "tenants instead (DESIGN.md §13)")
     ap.add_argument("--requests", type=int, default=16,
                     help="concurrent requests per serving tick")
+    ap.add_argument("--points", type=int, default=2048,
+                    help="multi-tenant: points per tenant")
+    ap.add_argument("--dim", type=int, default=8,
+                    help="multi-tenant: point dimension")
     ap.add_argument("--max-resident", type=int, default=4,
                     help="LRU bound on tenants holding device state")
     ap.add_argument("--telemetry", action="store_true",
@@ -279,6 +323,7 @@ def main(argv=None) -> int:
                     help="'prometheus' additionally dumps the registry "
                          "in Prometheus text format after the run")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.serve_tenants:
         return run_multi_tenant(args)

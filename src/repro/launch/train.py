@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.ckpt import checkpoint as ckpt
+from repro.launch.mesh import make_mesh
 from repro.configs.base import ShapeConfig, get_config, get_reduced
 from repro.data.pipeline import make_batch
 from repro.distributed import sharding as shard
@@ -62,7 +63,7 @@ def main(argv=None) -> int:
     cfg = dataclasses.replace(cfg, dtype=args.dtype)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
 
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_mesh((args.data, args.model), ("data", "model"))
     params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
     if args.dtype == "bfloat16":
         params = T.cast_params(params, jnp.bfloat16)

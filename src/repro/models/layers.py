@@ -21,7 +21,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 
-from repro.compat import shard_map
 
 _NEG_INF = -1.0e30
 
@@ -406,7 +405,7 @@ def kde_decode_attention_shardmap(q, k, v, kv_valid, top_p: int, bk: int,
         return out[:, :, None, :].astype(q_l.dtype)
 
     hspec = "model" if heads_sharded else None
-    shmap = shard_map(
+    shmap = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, hspec, None, None),
                   P(None, hspec, seq_axes, None),
@@ -588,7 +587,7 @@ def _moe_block_shardmap(p, cfg: ArchConfig, x, mesh, baxes,
         aux = e * jnp.sum(frac_tokens * frac_probs)
         return y, aux
 
-    shmap = shard_map(
+    shmap = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(baxes, None, None), P(None, "model"),
                   P("model", None, None), P("model", None, None),

@@ -18,10 +18,11 @@ constant).
 
 Measured mode (``measured_roofline``) takes a wall time and the modeled
 flops/bytes of the program that ran, and reports the achieved fraction of
-the spec's roofline: ``max(compute_s, memory_s, collective_s) / time_s``
+the chip's roofline: ``max(compute_s, memory_s, collective_s) / time_s``
 -- 1.0 means the run sits ON the roofline for its dominant resource.  The
-benchmarks' scaling campaigns record this per size so regressions show as
-a falling fraction, not just a rising microsecond count.
+peaks come from ``CHIP_PEAKS``, keyed by the ``device_kind`` JAX reports;
+a device missing from the table is an error, and a host-backend timing
+has no roofline at all (``roofline_summary`` reports it "not measured").
 """
 from __future__ import annotations
 
@@ -44,34 +45,39 @@ class ChipSpec:
         return dataclasses.asdict(self)
 
 
-# TPU v5e: 197 TF/s bf16, 819 GB/s HBM, 50 GB/s per ICI link.
-TPU_V5E = ChipSpec("tpu_v5e", 197e12, 819e9, 50e9)
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: TPU v5e -- Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect (four links,
+#: 50 GB/s each).
+CHIP_PEAKS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": ChipSpec("tpu_v5e", 197e12, 819e9, 50e9),
+}
 
-# Order-of-magnitude single host core (AVX2-class f32 FMA, DRAM stream):
-# the fallback spec when the process runs on the CPU backend, so measured
-# fractions stay O(0.1..1) instead of reading as 1e-4 of a TPU.
-HOST_CPU = ChipSpec("host_cpu", 5.0e10, 2.0e10, 2.0e10)
+#: the analytic dry-run's target chip
+TPU_V5E = CHIP_PEAKS["TPU v5 lite"]
 
-
-def chip_spec_for_backend(backend: Optional[str] = None) -> ChipSpec:
-    """Chip spec for an explicit backend name, or the process default
-    backend when None.  Unknown / GPU backends get the TPU spec (the
-    campaign's normalization target) -- pass an explicit ``ChipSpec`` to
-    the term builders to override."""
-    if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    return HOST_CPU if backend == "cpu" else TPU_V5E
+NOT_MEASURED = "not measured"
 
 
-# Back-compat module constants (== TPU_V5E); roofline_terms defaults to
-# them so the dry-run artifact numbers are unchanged.
-PEAK_FLOPS = TPU_V5E.peak_flops      # bf16 / chip
-HBM_BW = TPU_V5E.hbm_bw              # bytes/s / chip
-LINK_BW = TPU_V5E.link_bw            # bytes/s / link
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Published peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}: add them to CHIP_PEAKS with "
+                         f"their source") from None
+
+
+def device_chip_spec(device=None) -> Optional[ChipSpec]:
+    """Peaks of the device a measurement runs on (default: the first JAX
+    device): None on the CPU backend, whose timings have no roofline."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    return chip_spec(device.device_kind)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -318,13 +324,10 @@ class MeasuredRoofline:
 
 
 def measured_roofline(time_s: float, flops: float, bytes_moved: float,
-                      spec: Optional[ChipSpec] = None, chips: int = 1,
+                      spec: ChipSpec, chips: int = 1,
                       coll_bytes_per_device: float = 0.0) -> MeasuredRoofline:
     """Roofline placement of a measured run: modeled flops/bytes of the
-    program that ran, observed wall seconds, backend-configurable peaks
-    (``chip_spec_for_backend()`` when ``spec`` is None)."""
-    if spec is None:
-        spec = chip_spec_for_backend()
+    program that ran, observed wall seconds, the chip's published peaks."""
     compute_s = flops / (chips * spec.peak_flops)
     memory_s = bytes_moved / (chips * spec.hbm_bw)
     collective_s = coll_bytes_per_device / spec.link_bw
@@ -338,3 +341,17 @@ def measured_roofline(time_s: float, flops: float, bytes_moved: float,
         achieved_fraction=max(compute_s, memory_s, collective_s) / t,
         achieved_flops=flops / t, achieved_bw=bytes_moved / t,
         spec=spec.name, chips=chips)
+
+
+def roofline_summary(spec: Optional[ChipSpec], time_s: float, flops: float,
+                     bytes_moved: float, chips: int = 1,
+                     coll_bytes_per_device: float = 0.0) -> dict:
+    """``{fraction, dominant, achieved_bw}`` of a measured run on ``spec``
+    (``device_chip_spec()``), or ``{"fraction": "not measured"}`` when the
+    run had no chip (``spec`` None)."""
+    if spec is None:
+        return dict(fraction=NOT_MEASURED)
+    mr = measured_roofline(time_s, flops, bytes_moved, spec, chips=chips,
+                           coll_bytes_per_device=coll_bytes_per_device)
+    return dict(fraction=mr.achieved_fraction, dominant=mr.dominant,
+                achieved_bw=mr.achieved_bw)

@@ -2,14 +2,13 @@
 GridHBE equivalence, §2-contract level-1 reads, the ``level1="hash"``
 sampler hybrid, estimator="hash" pipelines, and the sharded one-psum
 query schedule (subprocesses own their XLA_FLAGS)."""
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.core.kde.base import ExactKDE, make_estimator
 from repro.core.kde.hashed import HashedKDE
 from repro.core.kde.hbe import GridHBE
@@ -20,13 +19,7 @@ from repro.kernels.kde_sampler import ops as sops
 
 
 def _run(code: str, devices: int = 8) -> str:
-    full = (f'import os\nos.environ["XLA_FLAGS"] = '
-            f'"--xla_force_host_platform_device_count={devices}"\n'
-            f'import sys; sys.path.insert(0, "src")\n' + code)
-    p = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd=".")
-    assert p.returncode == 0, p.stderr[-1200:]
-    return p.stdout
+    return subproc.run_devices(code, devices, tail=1200)
 
 
 @pytest.fixture(scope="module")
@@ -313,7 +306,7 @@ rng = np.random.default_rng(0)
 n, d = 700, 8
 x = rng.normal(0, 1.0, (n, d)).astype(np.float32)
 ker = gaussian(bandwidth=2.0)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 tab = ShardedHashTable(mesh, x, ker, seed=3)
 y = jnp.asarray(x[:32])
 key = jax.random.PRNGKey(5)

@@ -4,7 +4,6 @@ histogram determinism, exporter schema validation, and an 8-device
 subprocess proof that the counter payload adds ZERO collectives to the §9
 one-psum-per-draw schedule."""
 import json
-import subprocess
 import sys
 
 import jax
@@ -12,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.obs import counters as C
 from repro.obs import export
 from repro.obs import metrics as M
@@ -235,7 +235,7 @@ from repro.obs import counters as C
 rng = np.random.default_rng(0)
 n, bsz = 200, 16
 x = rng.normal(0, 0.6, (n, 5)).astype(np.float32)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 eng = ShardedBlocks(mesh, x, gaussian(1.0), block_size=bsz, exact=True)
 src = jnp.asarray(rng.integers(0, n, 48), jnp.int32)
 key = jax.random.PRNGKey(1)
@@ -250,10 +250,4 @@ want = eng._l1_evals(w) + w * eng.block_size * eng.num_shards
 assert t["evals"] == want, (t["evals"], want)
 print("OBS_SHARDED_OK")
 """
-    full = ('import os\nos.environ["XLA_FLAGS"] = '
-            '"--xla_force_host_platform_device_count=8"\n'
-            'import sys; sys.path.insert(0, "src")\n' + code)
-    p = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd=".")
-    assert p.returncode == 0, p.stderr[-1200:]
-    assert "OBS_SHARDED_OK" in p.stdout
+    assert "OBS_SHARDED_OK" in subproc.run_devices(code, 8, tail=1200)

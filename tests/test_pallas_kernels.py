@@ -240,3 +240,23 @@ def test_kde_attention_exact_when_all_blocks_selected():
     exact = ka.exact_decode_attention(jnp.asarray(q), jnp.asarray(k),
                                       jnp.asarray(v))
     np.testing.assert_allclose(np.asarray(out), np.asarray(exact), atol=1e-4)
+
+
+# ----------------------------------------------------------- platform choice
+def test_platform_decides_pallas_and_interpret(monkeypatch):
+    """One function decides: Pallas (compiled) by default on a TPU for the
+    kinds the kernels implement, jnp elsewhere, interpret only off-TPU."""
+    from repro.core.kde.base import ExactBlockKDE, ExactKDE
+    from repro.core.kernels_fn import Kernel
+    from repro.kernels import platform
+    x = RNG.normal(0, 0.5, (64, 3)).astype(np.float32)
+    assert platform.resolve() == (False, True)
+    assert not ExactKDE(x, gaussian(1.0)).use_pallas
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    assert platform.resolve(kind="gaussian") == (True, False)
+    assert ExactKDE(x, gaussian(1.0)).use_pallas
+    assert ExactBlockKDE(x, laplacian(1.0), block_size=16).use_pallas
+    custom = Kernel("custom", gaussian(1.0).pairwise, None, 1.0)
+    assert not ExactKDE(x, custom).use_pallas
+    with pytest.raises(ValueError):
+        platform.resolve(interpret=True)

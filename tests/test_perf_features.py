@@ -1,14 +1,13 @@
 """Regression tests for the §Perf optimizations (EXPERIMENTS.md):
 chunked attention, context-parallel prefill, shard_map MoE, shard_map KDE
 decode.  Multi-device checks run in subprocesses with their own XLA_FLAGS."""
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.models import layers as L
 
 
@@ -16,13 +15,7 @@ RNG = np.random.default_rng(7)
 
 
 def _run(code: str, devices: int = 8) -> str:
-    full = (f'import os\nos.environ["XLA_FLAGS"] = '
-            f'"--xla_force_host_platform_device_count={devices}"\n'
-            f'import sys; sys.path.insert(0, "src")\n' + code)
-    p = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd=".")
-    assert p.returncode == 0, p.stderr[-1500:]
-    return p.stdout
+    return subproc.run_devices(code, devices, tail=1500)
 
 
 # ------------------------------------------------------- chunked attention
@@ -68,7 +61,7 @@ from repro.roofline.analysis import collective_bytes
 
 cfg = get_reduced("yi_6b")
 shape = ShapeConfig("p", 256, 4, "prefill")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 params_s = jax.eval_shape(lambda: T.cast_params(
     T.init_params(jax.random.PRNGKey(0), cfg), jnp.bfloat16))
 p_sh = shard.param_shardings(params_s, mesh)
@@ -103,7 +96,7 @@ params = T.init_params(jax.random.PRNGKey(0), cfg)
 shape = ShapeConfig("p", 64, 2, "train")
 batch = {k: jnp.asarray(v) for k, v in make_batch(cfg, shape, 0).items()}
 ref, _ = T.forward(params, cfg, batch, remat=False)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with activation_sharding(mesh, ("data",), seq_mode=True):
     got, _ = jax.jit(lambda p, b: T.forward(p, cfg, b, remat=False))(params, batch)
 np.testing.assert_allclose(np.asarray(ref), np.asarray(got), atol=2e-3)
@@ -124,7 +117,7 @@ params = T.init_params(jax.random.PRNGKey(0), cfg)
 lp = jax.tree.map(lambda a: a[0], params["layers"])
 x = jnp.asarray(np.random.default_rng(0).normal(
     0, 0.5, (4, 16, cfg.d_model)).astype(np.float32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))  # 4 experts over model=4
+mesh = make_mesh((2, 4), ("data", "model"))  # 4 experts over model=4
 y_ref, aux_ref = L.moe_block_dense(lp["mlp"], cfg, x)
 with L.activation_sharding(mesh, ("data",)):
     y_sm, aux_sm = jax.jit(lambda p, x: L.moe_block(p, cfg, x,
@@ -148,7 +141,7 @@ params = T.init_params(jax.random.PRNGKey(0), cfg)
 lp = jax.tree.map(lambda a: a[0], params["layers"])
 x = jnp.asarray(np.random.default_rng(1).normal(
     0, 0.5, (4, 8, cfg.d_model)).astype(np.float32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def loss_ref(p, x):
     y, aux = L.moe_block_dense(p, cfg, x)
@@ -181,7 +174,7 @@ b, hq, hkv, S, hd = 1, 8, {hkv}, 1024, 32
 q = jnp.asarray(rng.normal(0, 1, (b, hq, 1, hd)).astype(np.float32))
 k = jnp.asarray(rng.normal(0, 0.3, (b, hkv, S, hd)).astype(np.float32))
 v = jnp.asarray(rng.normal(0, 1, (b, hkv, S, hd)).astype(np.float32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 kw = dict(top_p=4, bk=64, stride=4)
 with L.activation_sharding(mesh, ("data",)):
     out = L.kde_decode_attention_shardmap(q, k, v, 900, mesh=mesh,
@@ -203,7 +196,7 @@ rng = np.random.default_rng(0)
 q = jnp.asarray(rng.normal(0, 1, (1, 4, 1, 16)).astype(np.float32))
 k = jnp.asarray(rng.normal(0, 1, (1, 2, 96, 16)).astype(np.float32))
 v = jnp.asarray(rng.normal(0, 1, (1, 2, 96, 16)).astype(np.float32))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 r = L.kde_decode_attention_shardmap(q, k, v, 90, top_p=2, bk=64, stride=4,
                                     mesh=mesh, baxes=("data",))
 assert r is None

@@ -27,6 +27,7 @@ from repro.kernels import tuning
 from repro.kernels.kde_rowsum import kernel as rk
 from repro.kernels.kde_rowsum import ops as rs
 from repro.kernels.kde_sampler import ops as sops
+from repro.launch.mesh import make_mesh
 from repro.kernels.kde_sampler import ref as sref
 
 RNG = np.random.default_rng(7)
@@ -142,7 +143,7 @@ def test_bf16_rejected_for_non_l2_kernels_and_mesh():
     ndev = len(jax.devices())
     if ndev >= 2:
         from repro.core.sampling.edge import NeighborSampler
-        mesh = jax.make_mesh((ndev,), ("data",))
+        mesh = make_mesh((ndev,), ("data",))
         with pytest.raises(ValueError):
             NeighborSampler(x, gaussian(2.0), mode="blocked", mesh=mesh,
                             precision="bf16")

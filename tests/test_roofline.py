@@ -30,10 +30,11 @@ def test_while_trip_count_correction():
         return y
 
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("i",))
+
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("i",))
     sh = NamedSharding(mesh, P())
-    from repro.compat import shard_map
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P("i")))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P("i")))
     comp = g.lower(jax.ShapeDtypeStruct((8, 4), jnp.float32)).compile()
     cs = collective_bytes(comp.as_text())
     # one 8x4 f32 all-reduce (on a 1-device mesh it may be optimized away --
@@ -103,3 +104,18 @@ def test_roofline_terms():
     assert rl.dominant in ("compute", "memory", "collective")
     assert 0 < rl.useful_ratio <= 1.0
     assert rl.compute_s == pytest.approx(1e15 / (256 * 197e12))
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """Peaks come from the published table by device_kind; an unknown kind
+    is an error, and a CPU timing has no roofline ("not measured")."""
+    from repro.roofline import analysis as A
+    v5e = A.chip_spec("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError):
+        A.chip_spec("TPU v99")
+    assert A.device_chip_spec(jax.devices("cpu")[0]) is None
+    assert A.roofline_summary(None, 1.0, 1e9, 1e9) == {
+        "fraction": A.NOT_MEASURED}
+    rl = A.roofline_summary(v5e, 1.0, 197e12, 1.0)
+    assert rl["dominant"] == "compute" and abs(rl["fraction"] - 1.0) < 1e-12

@@ -9,8 +9,6 @@ All distributional assertions derive their keys from ``stats.ROOT_SEED``
 and compare against the precomputed critical values of ``tests/stats.py``
 (false-positive budget documented there)."""
 import json
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +16,7 @@ import numpy as np
 import pytest
 
 import stats
+import subproc
 from repro.core.kernels_fn import gaussian
 from repro.core.serving import (DEFAULT_BUCKETS, KernelGraphServable,
                                 shape_bucket)
@@ -418,7 +417,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.kernels_fn import gaussian
 from repro.core.serving import KernelGraphServable
 from repro.kernels.kde_sampler.sharded import collective_counts
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(%d)
 x = rng.normal(0, 0.6, (192, 4)).astype(np.float32)
 srv = KernelGraphServable()
@@ -457,10 +456,4 @@ np.testing.assert_array_equal(rp.result, np.asarray(p0))
 assert np.isfinite(rp.result).all() and (rp.result > 0).all()
 print("MESH_SERVE_OK")
 """ % stats.derive_seed("serving", "mesh")
-    full = ('import os\nos.environ["XLA_FLAGS"] = '
-            '"--xla_force_host_platform_device_count=8"\n'
-            'import sys; sys.path.insert(0, "src")\n' + code)
-    p = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd=".")
-    assert p.returncode == 0, p.stderr[-1500:]
-    assert "MESH_SERVE_OK" in p.stdout
+    assert "MESH_SERVE_OK" in subproc.run_devices(code, 8)

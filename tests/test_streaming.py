@@ -18,14 +18,13 @@ reads.  Randomized (stratified / hashed-FAR) paths agree in distribution,
 not per-draw -- those are covered by the TV test and the same-key hashed
 parity instead.
 """
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.core.dataset import DynamicDataset, coalesce_mutations
 from repro.core.kernels_fn import gaussian
 from repro.ft import guards as _g
@@ -335,13 +334,7 @@ def test_streaming_graph_end_to_end(data):
 # 8-device sharded case (subprocess owns its XLA_FLAGS)
 # --------------------------------------------------------------------- #
 def _run(code: str, devices: int = 8) -> str:
-    full = (f'import os\nos.environ["XLA_FLAGS"] = '
-            f'"--xla_force_host_platform_device_count={devices}"\n'
-            f'import sys; sys.path.insert(0, "src")\n' + code)
-    p = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd=".")
-    assert p.returncode == 0, p.stderr[-1500:]
-    return p.stdout
+    return subproc.run_devices(code, devices, tail=1500)
 
 
 def test_sharded_streaming_zero_collective_patch():
@@ -357,7 +350,7 @@ from repro.kernels.kde_sampler.sharded import ShardedBlocks, collective_counts
 ker = gaussian(1.0)
 rng = np.random.default_rng(0)
 x0 = rng.normal(0, 0.7, (192, 6)).astype(np.float32)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 ds = DynamicDataset(x0, capacity=256)
 eng = ShardedBlocks(mesh, ds.x_pad, ker, block_size=16, exact=True)
@@ -397,7 +390,7 @@ from repro.core.sampling.edge import NeighborSampler
 ker = gaussian(1.0)
 rng = np.random.default_rng(0)
 x0 = rng.normal(0, 0.7, (192, 6)).astype(np.float32)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 ds = DynamicDataset(x0, capacity=256)
 nbr = NeighborSampler(ds.x_pad, ker, mode="blocked", block_size=16,
                       exact_blocks=True, mesh=mesh, seed=3, dataset=ds)
@@ -427,7 +420,7 @@ from repro.kernels.kde_sampler.sharded import collective_counts
 ker = gaussian(1.0)
 rng = np.random.default_rng(0)
 x0 = rng.normal(0, 0.7, (192, 6)).astype(np.float32)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 ds = DynamicDataset(x0, capacity=256)
 tab = ShardedHashTable(mesh, np.asarray(ds.x_pad), ker, max_bucket=32,
                        num_far_samples=16, seed=2, live=ds.live_host,

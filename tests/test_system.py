@@ -1,6 +1,5 @@
 """End-to-end behaviour tests: the paper's pipelines composed, data layer,
 and the serving driver."""
-import os
 import subprocess
 import sys
 
@@ -8,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.configs.base import SHAPES, ShapeConfig, get_reduced
 from repro.core.cluster.spectral import cluster_accuracy, spectral_cluster
 from repro.core.kernels_fn import gaussian, laplacian, median_bandwidth
@@ -85,7 +85,7 @@ def test_token_split_covers_shapes():
 
 
 def test_serve_driver_runs():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = subproc.child_env()
     p = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "yi_6b",
          "--reduced", "--batch", "2", "--prompt-len", "16", "--gen", "4"],
@@ -95,7 +95,7 @@ def test_serve_driver_runs():
 
 
 def test_serve_driver_kde_attention():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = subproc.child_env()
     p = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "yi_6b",
          "--reduced", "--batch", "2", "--prompt-len", "32", "--gen", "4",

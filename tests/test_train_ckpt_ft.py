@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import subproc
 from repro.ckpt import checkpoint as ckpt
 from repro.configs.base import ShapeConfig, get_reduced
 from repro.data.pipeline import make_batch
@@ -100,7 +101,7 @@ def test_resume_determinism(tmp_path):
 
 def test_failure_injection_and_resume(tmp_path):
     """Kill the driver mid-run (exit 17); rerun resumes and finishes."""
-    env = dict(os.environ, PYTHONPATH="src")
+    env = subproc.child_env()
     ckdir = str(tmp_path / "ck")
     cmd = [sys.executable, "-m", "repro.launch.train", "--arch", "yi_6b",
            "--reduced", "--steps", "12", "--batch", "2", "--seq", "32",
@@ -119,9 +120,6 @@ def test_elastic_restore_different_mesh(tmp_path):
     """Checkpoint written under one sharding restores onto another mesh
     (data-axis resize) -- subprocess with 8 fake devices."""
     code = f"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys; sys.path.insert(0, "src")
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import get_reduced
@@ -130,10 +128,10 @@ from repro.distributed import sharding as shard
 from repro.ckpt import checkpoint as ckpt
 cfg = dataclasses.replace(get_reduced("yi_6b"), dtype="float32")
 params = T.init_params(jax.random.PRNGKey(0), cfg)
-mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+mesh4 = make_mesh((4, 2), ("data", "model"))
 p4 = jax.tree.map(jax.device_put, params, shard.param_shardings(params, mesh4))
 ckpt.save({str(tmp_path)!r}, 3, p4)
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 sh2 = shard.param_shardings(params, mesh2)
 restored, step = ckpt.restore({str(tmp_path)!r}, params, shardings=sh2)
 assert step == 3
@@ -141,9 +139,7 @@ for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 print("ELASTIC_OK")
 """
-    p = subprocess.run([sys.executable, "-c", code],
-                       capture_output=True, text=True, cwd=".")
-    assert "ELASTIC_OK" in p.stdout, p.stderr[-800:]
+    assert "ELASTIC_OK" in subproc.run_devices(code, 8, tail=800)
 
 
 def test_watchdog():
