@@ -1,0 +1,7 @@
+"""Idle share of the device over the serving window: 1 - busy / window,
+averaged over the devices used."""
+from chipbench import trace as _trace
+
+
+def reduce(ctx):
+    return _trace.idle_share(ctx["trace"])
