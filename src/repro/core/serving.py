@@ -416,27 +416,46 @@ class KernelGraphServable:
             stats.update(admissions=0, evictions=0, tick_ms=0.0,
                          realized_evals=0)
             return stats
+        with _m.span("serve.tick", requests=len(reqs)):
+            self._tick(reqs, stats)
+        self.served += stats["served"]
+        self.failed += stats["failed"]
+        self.ticks += 1
+        stats.update(admissions=self.admissions - adm0,
+                     evictions=self.evictions - ev0,
+                     tick_ms=1e3 * (time.perf_counter() - t0),
+                     realized_evals=self.device_counters["evals"] - evals0)
+        if _m.enabled():
+            self._record_metrics(reqs, stats)
+        return stats
+
+    def _tick(self, reqs, stats) -> None:
+        """The tick's phases, each under its span: admission (``serve.
+        admit``), the stale gate and group keys (``serve.group``), one
+        program per group, then the per-request tally."""
         needed = {r.tenant for r in reqs}
         admit_errors: dict = {}
-        for name in sorted(needed):
-            try:
-                self._admit(name, needed)
-            except Exception as e:     # noqa: BLE001 -- per-tenant isolation
-                admit_errors[name] = e
+        with _m.span("serve.admit", tenants=len(needed)):
+            for name in sorted(needed):
+                try:
+                    self._admit(name, needed)
+                except Exception as e:  # noqa: BLE001 -- per-tenant isolation
+                    admit_errors[name] = e
         groups: dict = {}
-        for r in reqs:
-            if r.tenant in admit_errors:
-                self._fail(r, admit_errors[r.tenant])
-                continue
-            t = self._tenants[r.tenant]
-            try:
-                if not self._gate_stale(r, t, stats):
+        with _m.span("serve.group", requests=len(reqs)):
+            for r in reqs:
+                if r.tenant in admit_errors:
+                    self._fail(r, admit_errors[r.tenant])
                     continue
-                gkey = self._group_key(r, t)
-            except Exception as e:     # noqa: BLE001 -- bad payload
-                self._fail(r, e)
-                continue
-            groups.setdefault(gkey, []).append(r)
+                t = self._tenants[r.tenant]
+                try:
+                    if not self._gate_stale(r, t, stats):
+                        continue
+                    gkey = self._group_key(r, t)
+                except Exception as e:     # noqa: BLE001 -- bad payload
+                    self._fail(r, e)
+                    continue
+                groups.setdefault(gkey, []).append(r)
         for key, grp in groups.items():
             # per-group fault isolation: one group blowing up (bad payload
             # dims, engine failure) fails ITS requests only -- the other
@@ -459,16 +478,6 @@ class KernelGraphServable:
                 stats["served"] += 1
             else:
                 stats["failed"] += 1
-        self.served += stats["served"]
-        self.failed += stats["failed"]
-        self.ticks += 1
-        stats.update(admissions=self.admissions - adm0,
-                     evictions=self.evictions - ev0,
-                     tick_ms=1e3 * (time.perf_counter() - t0),
-                     realized_evals=self.device_counters["evals"] - evals0)
-        if _m.enabled():
-            self._record_metrics(reqs, stats)
-        return stats
 
     def _record_metrics(self, reqs, stats) -> None:
         """Per-tenant / per-op latency histograms plus tick counters into
@@ -585,67 +594,105 @@ class KernelGraphServable:
 
     def _serve_flat_group(self, key, grp) -> None:
         """Serve one (tenant signature, op, bucket) group as ONE padded
-        vmap program over the stacked tenant arena."""
+        vmap program over the stacked tenant arena, in four spans:
+        ``serve.stage`` (arena, padded payloads, keys), ``serve.dispatch``
+        (the ``batched_*`` call), ``serve.readback`` (the host blocks on
+        the outputs) and ``serve.scatter``."""
         from repro.kernels.kde_sampler import ops as _ops
         op, wb = key[1], key[2]
-        names = sorted({r.tenant for r in grp})
-        tenants = [self._tenants[nm] for nm in names]
-        tmap = {nm: i for i, nm in enumerate(names)}
-        xa, xa_sq, hstate = self._arena(tenants)
-        # numpy inputs go straight to the jitted batch entry points: the
-        # C++ jit dispatch path stages them faster than per-array
-        # device_put, and this is the per-tick hot path
-        tidx = np.asarray([tmap[r.tenant] for r in grp], np.int32)
-        keys = _batch_keys([r.seed for r in grp])
-        cfg = tenants[0].nbr._cfg
-        if op == "sample":
-            widths = [len(np.asarray(r.payload["src"]).reshape(-1))
-                      for r in grp]
-            src = np.stack([_pad_idx(r.payload["src"], wb) for r in grp])
-            nb, prob, _, st = _ops.batched_fused_sample(
-                xa, xa_sq, tidx, src, keys, hstate=hstate, **cfg)
-            nb, prob = np.asarray(nb), np.asarray(prob)
-            res = [(nb[i, :w], prob[i, :w]) for i, w in enumerate(widths)]
-        elif op == "walk":
-            length = key[3]
-            widths = [len(np.asarray(r.payload["starts"]).reshape(-1))
-                      for r in grp]
-            starts = np.stack([_pad_idx(r.payload["starts"], wb)
-                               for r in grp])
-            wkeys = _split_batch(keys, length)
-            end, _, st, _ = _ops.batched_walk_scan(
-                xa, xa_sq, tidx, starts, wkeys, hstate=hstate,
-                rounds=0, slack=2.0, record_path=False, **cfg)
-            end = np.asarray(end)
-            res = [(end[i, :w], None) for i, w in enumerate(widths)]
-        elif op == "prob_of":
-            widths = [len(np.asarray(r.payload["src"]).reshape(-1))
-                      for r in grp]
-            src = np.stack([_pad_idx(r.payload["src"], wb) for r in grp])
-            dst = np.stack([_pad_idx(r.payload["dst"], wb) for r in grp])
-            prob, st = _ops.batched_prob_of(
-                xa, xa_sq, tidx, src, dst, keys, hstate=hstate, **cfg)
-            prob = np.asarray(prob)
-            res = [prob[i, :w] for i, w in enumerate(widths)]
-        elif op == "query":
-            widths = [len(np.atleast_2d(r.payload["y"])) for r in grp]
-            y = np.stack([_pad_pts(r.payload["y"], wb) for r in grp])
-            if tenants[0].nbr.level1 == "hash":
-                from repro.kernels.kde_hash import ops as _hops
-                hq = tenants[0].nbr.hash_estimator
-                est, _, st = _hops.batched_hashed_query(
-                    xa, tidx, y, hstate, keys, **hq._cfg)
-            else:
-                qkeys = ("kind", "inv_bw", "beta", "pairwise", "block_size",
-                         "num_blocks", "n", "s", "exact", "precision")
-                est, st = _ops.batched_kde_query(
-                    xa, xa_sq, tidx, y, keys,
-                    **{k: cfg[k] for k in qkeys})
-            est = np.asarray(est)
-            res = [est[i, :w] for i, w in enumerate(widths)]
-        else:                                          # pragma: no cover
-            raise ValueError(op)
-        self._scatter(grp, res, st)
+        meta = dict(op=op, requests=len(grp))
+        with _m.span("serve.stage", **meta):
+            names = sorted({r.tenant for r in grp})
+            tenants = [self._tenants[nm] for nm in names]
+            tmap = {nm: i for i, nm in enumerate(names)}
+            xa, xa_sq, hstate = self._arena(tenants)
+            # numpy inputs go straight to the jitted batch entry points:
+            # the C++ jit dispatch path stages them faster than per-array
+            # device_put, and this is the per-tick hot path
+            tidx = np.asarray([tmap[r.tenant] for r in grp], np.int32)
+            keys = _batch_keys([r.seed for r in grp])
+            cfg = tenants[0].nbr._cfg
+            # ``call`` dispatches the group's program and returns (outputs
+            # to read back, status words); ``lane`` cuts request i's
+            # result of width w out of the host outputs
+            if op == "sample":
+                widths = [len(np.asarray(r.payload["src"]).reshape(-1))
+                          for r in grp]
+                src = np.stack([_pad_idx(r.payload["src"], wb)
+                                for r in grp])
+
+                def call():
+                    nb, prob, _, st = _ops.batched_fused_sample(
+                        xa, xa_sq, tidx, src, keys, hstate=hstate, **cfg)
+                    return (nb, prob), st
+
+                def lane(out, i, w):
+                    return out[0][i, :w], out[1][i, :w]
+            elif op == "walk":
+                length = key[3]
+                widths = [len(np.asarray(r.payload["starts"]).reshape(-1))
+                          for r in grp]
+                starts = np.stack([_pad_idx(r.payload["starts"], wb)
+                                   for r in grp])
+
+                def call():
+                    wkeys = _split_batch(keys, length)
+                    end, _, st, _ = _ops.batched_walk_scan(
+                        xa, xa_sq, tidx, starts, wkeys, hstate=hstate,
+                        rounds=0, slack=2.0, record_path=False, **cfg)
+                    return (end,), st
+
+                def lane(out, i, w):
+                    return out[0][i, :w], None
+            elif op == "prob_of":
+                widths = [len(np.asarray(r.payload["src"]).reshape(-1))
+                          for r in grp]
+                src = np.stack([_pad_idx(r.payload["src"], wb)
+                                for r in grp])
+                dst = np.stack([_pad_idx(r.payload["dst"], wb)
+                                for r in grp])
+
+                def call():
+                    prob, st = _ops.batched_prob_of(
+                        xa, xa_sq, tidx, src, dst, keys, hstate=hstate,
+                        **cfg)
+                    return (prob,), st
+
+                def lane(out, i, w):
+                    return out[0][i, :w]
+            elif op == "query":
+                widths = [len(np.atleast_2d(r.payload["y"])) for r in grp]
+                y = np.stack([_pad_pts(r.payload["y"], wb) for r in grp])
+                if tenants[0].nbr.level1 == "hash":
+                    from repro.kernels.kde_hash import ops as _hops
+                    hq = tenants[0].nbr.hash_estimator
+
+                    def call():
+                        est, _, st = _hops.batched_hashed_query(
+                            xa, tidx, y, hstate, keys, **hq._cfg)
+                        return (est,), st
+                else:
+                    qkeys = ("kind", "inv_bw", "beta", "pairwise",
+                             "block_size", "num_blocks", "n", "s", "exact",
+                             "precision")
+
+                    def call():
+                        est, st = _ops.batched_kde_query(
+                            xa, xa_sq, tidx, y, keys,
+                            **{k: cfg[k] for k in qkeys})
+                        return (est,), st
+
+                def lane(out, i, w):
+                    return out[0][i, :w]
+            else:                                      # pragma: no cover
+                raise ValueError(op)
+        with _m.span("serve.dispatch", **meta):
+            out, st = call()
+        with _m.span("serve.readback", **meta):
+            out = [np.asarray(a) for a in out]
+        with _m.span("serve.scatter", **meta):
+            self._scatter(grp, [lane(out, i, w)
+                                for i, w in enumerate(widths)], st)
 
     def _serve_mesh_group(self, key, grp) -> None:
         """Serve a mesh tenant's group through its sharded engine: draws
@@ -662,63 +709,85 @@ class KernelGraphServable:
         t = self._tenants[name]
         nbr = t.nbr
         engine = nbr._engine
+        meta = dict(op=op, requests=len(grp))
         if op == "walk":
             length = key[3]
             res, words = [], []
             for r in grp:
-                starts = jnp.asarray(np.asarray(r.payload["starts"]),
-                                     jnp.int32)
-                wkeys = jax.random.split(jax.random.PRNGKey(r.seed), length)
-                end, _, st, _ = engine.walk_scan(starts, wkeys, rounds=0,
-                                                 slack=2.0,
-                                                 record_path=False)
-                res.append((np.asarray(end), None))
-                words.append(np.asarray(st, np.uint32))
-            self._scatter(grp, res, np.asarray(words))
+                with _m.span("serve.stage", **meta):
+                    starts = jnp.asarray(np.asarray(r.payload["starts"]),
+                                         jnp.int32)
+                    wkeys = jax.random.split(jax.random.PRNGKey(r.seed),
+                                             length)
+                with _m.span("serve.dispatch", **meta):
+                    end, _, st, _ = engine.walk_scan(
+                        starts, wkeys, rounds=0, slack=2.0,
+                        record_path=False)
+                with _m.span("serve.readback", **meta):
+                    res.append((np.asarray(end), None))
+                    words.append(np.asarray(st, np.uint32))
+            with _m.span("serve.scatter", **meta):
+                self._scatter(grp, res, np.asarray(words))
             return
         if op == "query":
-            widths = [len(np.atleast_2d(r.payload["y"])) for r in grp]
-            y = jnp.asarray(np.concatenate(
-                [np.atleast_2d(np.asarray(r.payload["y"], np.float32))
-                 for r in grp]))
-            est = np.asarray(nbr.blocks.query(y))
-            offs = np.cumsum([0] + widths)
-            res = [est[offs[i]:offs[i + 1]] for i in range(len(grp))]
-            st = np.full(len(grp), np.uint32(
-                getattr(nbr.blocks, "last_status", 0)), np.uint32)
-            self._scatter(grp, res, st)
+            with _m.span("serve.stage", **meta):
+                widths = [len(np.atleast_2d(r.payload["y"])) for r in grp]
+                y = jnp.asarray(np.concatenate(
+                    [np.atleast_2d(np.asarray(r.payload["y"], np.float32))
+                     for r in grp]))
+            with _m.span("serve.dispatch", **meta):
+                est = nbr.blocks.query(y)
+            with _m.span("serve.readback", **meta):
+                est = np.asarray(est)
+            with _m.span("serve.scatter", **meta):
+                offs = np.cumsum([0] + widths)
+                res = [est[offs[i]:offs[i + 1]] for i in range(len(grp))]
+                st = np.full(len(grp), np.uint32(
+                    getattr(nbr.blocks, "last_status", 0)), np.uint32)
+                self._scatter(grp, res, st)
             return
-        key0 = jax.random.PRNGKey(grp[0].seed)
-        for r in grp[1:]:
-            key0 = jax.random.fold_in(key0, r.seed)
-        widths = [len(np.asarray(r.payload["src"]).reshape(-1))
-                  for r in grp]
-        src = jnp.asarray(np.concatenate(
-            [np.asarray(r.payload["src"]).reshape(-1) for r in grp]),
-            jnp.int32)
-        offs = np.cumsum([0] + widths)
-        if op == "sample":
-            nb, prob, _, cw = engine.fused_sample(src, key0)
-            nb, prob = np.asarray(nb), np.asarray(prob)
-            res = [(nb[offs[i]:offs[i + 1]], prob[offs[i]:offs[i + 1]])
-                   for i in range(len(grp))]
-        else:                                          # prob_of
-            dst = jnp.asarray(np.concatenate(
-                [np.asarray(r.payload["dst"]).reshape(-1) for r in grp]),
+        with _m.span("serve.stage", **meta):
+            key0 = jax.random.PRNGKey(grp[0].seed)
+            for r in grp[1:]:
+                key0 = jax.random.fold_in(key0, r.seed)
+            widths = [len(np.asarray(r.payload["src"]).reshape(-1))
+                      for r in grp]
+            src = jnp.asarray(np.concatenate(
+                [np.asarray(r.payload["src"]).reshape(-1) for r in grp]),
                 jnp.int32)
-            bs, cw = engine.masked_block_sums(src, key0)
-            prob_dev, cw2 = engine.prob_of_from_block_sums(src, dst, bs)
-            # fold the level-1 read word into the prob-of word and flag
-            # the read itself -- NONFINITE_RESULT on NaN/Inf
-            cw = _c.fold_status(_c.fold(cw, cw2),
-                                _g.result_status(prob_dev))
-            prob = np.asarray(prob_dev)
-            res = [prob[offs[i]:offs[i + 1]] for i in range(len(grp))]
-        # ONE counter word covers the whole concatenated draw batch: note
-        # it once (replicating it per request would multiply-count the
-        # realized work) and fan only its status bits out to the group
-        st = self.device_counters.note(cw)
-        self._scatter(grp, res, np.full(len(grp), np.uint32(st), np.uint32))
+            offs = np.cumsum([0] + widths)
+            if op == "prob_of":
+                dst = jnp.asarray(np.concatenate(
+                    [np.asarray(r.payload["dst"]).reshape(-1)
+                     for r in grp]), jnp.int32)
+        with _m.span("serve.dispatch", **meta):
+            if op == "sample":
+                nb, prob, _, cw = engine.fused_sample(src, key0)
+                out = (nb, prob)
+            else:                                      # prob_of
+                bs, cw = engine.masked_block_sums(src, key0)
+                prob_dev, cw2 = engine.prob_of_from_block_sums(src, dst, bs)
+                # fold the level-1 read word into the prob-of word and flag
+                # the read itself -- NONFINITE_RESULT on NaN/Inf
+                cw = _c.fold_status(_c.fold(cw, cw2),
+                                    _g.result_status(prob_dev))
+                out = (prob_dev,)
+        with _m.span("serve.readback", **meta):
+            out = [np.asarray(a) for a in out]
+        with _m.span("serve.scatter", **meta):
+            if op == "sample":
+                res = [(out[0][offs[i]:offs[i + 1]],
+                        out[1][offs[i]:offs[i + 1]])
+                       for i in range(len(grp))]
+            else:
+                res = [out[0][offs[i]:offs[i + 1]] for i in range(len(grp))]
+            # ONE counter word covers the whole concatenated draw batch:
+            # note it once (replicating it per request would multiply-count
+            # the realized work) and fan only its status bits out to the
+            # group
+            st = self.device_counters.note(cw)
+            self._scatter(grp, res,
+                          np.full(len(grp), np.uint32(st), np.uint32))
 
     # ------------------------------------------------------------------ #
     def report(self) -> dict:

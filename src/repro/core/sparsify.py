@@ -22,6 +22,7 @@ import numpy as np
 from repro.core.kernels_fn import Kernel
 from repro.core.sampling.edge import NeighborSampler, shared_level1_estimator
 from repro.core.sampling.vertex import DegreeSampler
+from repro.obs import metrics as _m
 
 
 @dataclasses.dataclass
@@ -91,28 +92,42 @@ def spectral_sparsify(x, kernel: Kernel, num_edges: int,
     ``mesh=`` path the hashed hybrid covers degrees only (the collective
     draws stay on the §9 blocked engine).
     """
+    with _m.span("sparsify.call", edges=int(num_edges)):
+        return _sparsify(x, kernel, int(num_edges), estimator, seed, batch,
+                         exact_blocks, samples_per_block, mesh)
+
+
+def _sparsify(x, kernel, t, estimator, seed, batch, exact_blocks,
+              samples_per_block, mesh) -> SparseGraph:
+    """The four phases of :func:`spectral_sparsify`, each under its span:
+    sampler build, degree sweep, edge scan, graph assembly."""
     n = int(x.shape[0])
-    t = int(num_edges)
-    nbr = NeighborSampler(x, kernel, mode="blocked", seed=seed + 2,
-                          exact_blocks=exact_blocks,
-                          samples_per_block=samples_per_block, mesh=mesh,
-                          level1="hash" if estimator == "hash"
-                          and mesh is None else "blocked")
-    # Degree preprocessing (Algorithm 4.3) against the sampler's own
-    # level-1 structure whenever it implements the requested estimator --
-    # one KDE build and one preprocessing sweep over x, not two.  The
-    # sampler's structure is exact (ExactBlockKDE) iff exact_blocks.
-    est = shared_level1_estimator(nbr, estimator, seed=seed)
-    deg = DegreeSampler(est, seed=seed + 1,
-                        mesh=mesh if est is nbr.blocks else None)
-    u, v, w, _, _ = nbr.edge_batches(deg.cdf_device, deg.degrees_device,
-                                     deg.total, t, batch=batch)
-    g = SparseGraph(n, np.asarray(u, np.int64), np.asarray(v, np.int64),
-                    np.asarray(w, np.float64))
-    g.kernel_evals = nbr.evals + (0 if est is nbr.blocks else est.evals)
-    est_words = getattr(est, "device_counters", None)
-    g.device_evals = nbr.device_counters["evals"] + (
-        est_words["evals"] if est_words is not None else 0)
+    with _m.span("sparsify.sampler"):
+        nbr = NeighborSampler(x, kernel, mode="blocked", seed=seed + 2,
+                              exact_blocks=exact_blocks,
+                              samples_per_block=samples_per_block,
+                              mesh=mesh,
+                              level1="hash" if estimator == "hash"
+                              and mesh is None else "blocked")
+        # Degree preprocessing (Algorithm 4.3) against the sampler's own
+        # level-1 structure whenever it implements the requested estimator
+        # -- one KDE build and one preprocessing sweep over x, not two.
+        # The sampler's structure is exact (ExactBlockKDE) iff
+        # exact_blocks.
+        est = shared_level1_estimator(nbr, estimator, seed=seed)
+    with _m.span("sparsify.degrees"):
+        deg = DegreeSampler(est, seed=seed + 1,
+                            mesh=mesh if est is nbr.blocks else None)
+        cdf, degs, total = deg.cdf_device, deg.degrees_device, deg.total
+    with _m.span("sparsify.edges"):
+        u, v, w, _, _ = nbr.edge_batches(cdf, degs, total, t, batch=batch)
+    with _m.span("sparsify.graph"):
+        g = SparseGraph(n, np.asarray(u, np.int64), np.asarray(v, np.int64),
+                        np.asarray(w, np.float64))
+        g.kernel_evals = nbr.evals + (0 if est is nbr.blocks else est.evals)
+        est_words = getattr(est, "device_counters", None)
+        g.device_evals = nbr.device_counters["evals"] + (
+            est_words["evals"] if est_words is not None else 0)
     # degree preprocessing + one forward level-1 read per drawn edge (the
     # reverse probability collapses onto the preprocessed degrees)
     drawn = ((t + batch - 1) // batch) * batch

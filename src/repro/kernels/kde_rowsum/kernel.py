@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.kde_sampler.ref import _finish_l2_bf16, check_precision
+from repro.obs import metrics as _m
 
 _L2_KINDS = ("gaussian", "exponential", "rational_quadratic")
 
@@ -130,6 +131,7 @@ def _blocksum_kernel(q_ref, x_ref, o_ref, *, kind, inv_bw, beta, precision,
     put_column(o_ref, col, jnp.sum(kv, axis=1, keepdims=True))
 
 
+@_m.scope("level1")
 def rowsum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
                   beta: float = 1.0, bm: int = 128, bn: int = 512,
                   interpret: bool = False,
@@ -141,6 +143,7 @@ def rowsum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
                              beta=beta, precision=precision)
     out = pl.pallas_call(
         body,
+        name="_rowsum_kernel",
         grid=(m // bm, n // bn),
         in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
                   pl.BlockSpec((bn, d), lambda i, j: (j, 0))],
@@ -156,6 +159,7 @@ def rowsum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
     return out[:, 0]
 
 
+@_m.scope("level1")
 def blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
                     beta: float = 1.0, bm: int = 128, bn: int = 256,
                     interpret: bool = False,
@@ -168,6 +172,7 @@ def blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, kind: str, inv_bw: float,
                              beta=beta, precision=precision, group=group)
     out = pl.pallas_call(
         body,
+        name="_blocksum_kernel",
         grid=(m // bm, nb),
         in_specs=[pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
                   pl.BlockSpec((bn, d), lambda i, j: (j, 0))],

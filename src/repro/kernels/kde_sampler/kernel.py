@@ -27,6 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.kde_rowsum.kernel import (LANES, _tile_kernel_values,
                                              lane_group, put_column)
+from repro.obs import metrics as _m
 
 _FLOOR = 1e-12  # == ref.BLOCK_SUM_FLOOR
 
@@ -97,6 +98,7 @@ def _row_specs(bm, d, bn):
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)))
 
 
+@_m.scope("level1")
 def masked_blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, own: jnp.ndarray,
                            kind: str, inv_bw: float, beta: float = 1.0,
                            bm: int = 128, bn: int = 256,
@@ -116,6 +118,7 @@ def masked_blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, own: jnp.ndarray,
                              group=group)
     bs = pl.pallas_call(
         body,
+        name="_masked_blocksum_kernel",
         grid=(m // bm, nb),
         in_specs=list(_row_specs(bm, d, bn)),
         out_specs=pl.BlockSpec((bm, group), lambda i, j: (i, j // group)),
@@ -128,6 +131,7 @@ def masked_blocksum_pallas(q: jnp.ndarray, x: jnp.ndarray, own: jnp.ndarray,
     return bs[:, :nb]
 
 
+@_m.scope("level1")
 def sample_block_pallas(q: jnp.ndarray, x: jnp.ndarray, own: jnp.ndarray,
                         gumbel: jnp.ndarray, kind: str, inv_bw: float,
                         beta: float = 1.0, bm: int = 128, bn: int = 256,
@@ -146,6 +150,7 @@ def sample_block_pallas(q: jnp.ndarray, x: jnp.ndarray, own: jnp.ndarray,
     gp = jnp.pad(gumbel, ((0, 0), (0, nbp - nb)))
     blk, pb, tot, bs = pl.pallas_call(
         body,
+        name="_sample_block_kernel",
         grid=(m // bm, nb),
         in_specs=[q_spec, own_spec, tile, x_spec],
         out_specs=[row, row, row, tile],

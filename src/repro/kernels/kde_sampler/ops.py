@@ -65,6 +65,7 @@ from repro.kernels.kde_rowsum.ops import _PAD_OFFSET, _pad_rows
 from repro.kernels.kde_sampler import kernel as _k
 from repro.kernels.kde_sampler import ref as _ref
 from repro.obs import counters as _c
+from repro.obs import metrics as _m
 
 TRACE_COUNTS = collections.Counter()
 
@@ -109,6 +110,7 @@ def _jit(fn):
 # level-1: (m, B) block-sum reads
 # --------------------------------------------------------------------- #
 @_jit
+@_m.scope("level1")
 def stratified_block_sums(y, x, x_sq, key, *, kind, inv_bw, beta, pairwise,
                           block_size, num_blocks, n, s, precision="f32"):
     """Per-block uniform-subsample estimates of the block sums, (m, B).
@@ -157,6 +159,7 @@ def stratified_block_sums(y, x, x_sq, key, *, kind, inv_bw, beta, pairwise,
 
 
 @_jit
+@_m.scope("level1")
 def exact_block_sums(y, x, x_sq, *, kind, inv_bw, beta, pairwise,
                      block_size, num_blocks, n, precision="f32"):
     """Exact (m, B) block sums: one dense vectorized sweep, zero host loops.
@@ -183,6 +186,7 @@ def exact_block_sums(y, x, x_sq, *, kind, inv_bw, beta, pairwise,
     return bs, _word(bs)
 
 
+@_m.scope("level1")
 def _pallas_pad(x, src, bm, block_size):
     """Shared Pallas preamble: query rows padded to a bm multiple, own-block
     indices padded with the -1 sentinel, dataset padded to a block_size
@@ -195,6 +199,7 @@ def _pallas_pad(x, src, bm, block_size):
     return q, own, xp, rem
 
 
+@_m.scope("level1")
 def _masked_block_sums(x, x_sq, src, key, *, kind, inv_bw, beta, pairwise,
                        block_size, num_blocks, n, s, exact, precision="f32"):
     """Level-1 sums for a frontier of dataset indices, own-block corrected
@@ -252,6 +257,7 @@ def _block_views(x, x_sq, block_size):
     return _ref.block_views(x, x_sq, block_size)
 
 
+@_m.scope("level2")
 def _level2_kv(x, x_sq, views, src, blk, *, kind, inv_bw, beta, pairwise,
                block_size, n):
     """See ``ref.level2_row`` -- shared with the oracles."""
@@ -259,12 +265,13 @@ def _level2_kv(x, x_sq, views, src, blk, *, kind, inv_bw, beta, pairwise,
                            block_size, n, pairwise)
 
 
-_level2_draw = _ref.level2_draw
+_level2_draw = _m.scope("level2")(_ref.level2_draw)
 
 
 _choose_block = _ref.choose_block
 
 
+@_m.scope("level2")
 def _sample_core(x, x_sq, views, src, bs, key, *, kind, inv_bw, beta,
                  pairwise, block_size, n):
     """(block draw -> level-2 row -> neighbor draw) from given level-1 sums.
@@ -274,6 +281,7 @@ def _sample_core(x, x_sq, views, src, bs, key, *, kind, inv_bw, beta,
                                  beta, block_size, n, pairwise)
 
 
+@_m.scope("level2")
 def _walk_sample_core(x, x_sq, views, src, bs, key, *, kind, inv_bw, beta,
                       pairwise, block_size, n, num_blocks):
     """``sample_from_sums`` with the two-level inverse-CDF draws
@@ -316,13 +324,14 @@ def _fused_sample(x, x_sq, src, key, hstate=None, *, kind, inv_bw, beta,
     elif exact and use_pallas:
         # Fully fused level-1: block sums + Gumbel-max draw in one Pallas pass.
         k_g, k_in = jax.random.split(k_rest)
-        q, own, xp, rem = _pallas_pad(x, src, bm, block_size)
-        gp = jnp.pad(jax.random.gumbel(k_g, (w, num_blocks)),
-                     ((0, rem), (0, 0)))
-        blk, pb, _, bs = _k.sample_block_pallas(
-            q, xp, own, gp, kind, inv_bw, beta, bm=bm, bn=block_size,
-            interpret=interpret, precision=precision)
-        blk, pb, bs = blk[:w], pb[:w], bs[:w]
+        with jax.named_scope("level1"):
+            q, own, xp, rem = _pallas_pad(x, src, bm, block_size)
+            gp = jnp.pad(jax.random.gumbel(k_g, (w, num_blocks)),
+                         ((0, rem), (0, 0)))
+            blk, pb, _, bs = _k.sample_block_pallas(
+                q, xp, own, gp, kind, inv_bw, beta, bm=bm, bn=block_size,
+                interpret=interpret, precision=precision)
+            blk, pb, bs = blk[:w], pb[:w], bs[:w]
         kv, live, cols_c = _level2_kv(x, x_sq, views, src, blk, kind=kind,
                                       inv_bw=inv_bw, beta=beta,
                                       pairwise=pairwise,
@@ -382,6 +391,7 @@ def sample_from_block_sums(x, x_sq, src, bs, key, *, kind, inv_bw, beta,
     return nb, prob, _c.word(status=st, evals=w * block_size, draws=w)
 
 
+@_m.scope("level2")
 def _prob_core(x, x_sq, views, src, dst, bs, *, kind, inv_bw, beta, pairwise,
                block_size, n):
     """q(dst | src) from given level-1 sums of the src frontier.  Mirrors
@@ -421,6 +431,7 @@ def prob_of_from_block_sums(x, x_sq, src, dst, bs, *, kind, inv_bw, beta,
 # --------------------------------------------------------------------- #
 # fused Algorithm 5.1 edge batches + batched LRA sketch rows
 # --------------------------------------------------------------------- #
+@_m.scope("level1")
 def _masked_sums_any(x, x_sq, src, key, hstate=None, *, kind, inv_bw, beta,
                      pairwise, block_size, num_blocks, n, s, exact,
                      use_pallas, interpret, bm, level1="blocked", num_far=64,
@@ -494,6 +505,7 @@ def _edge_batch_core(x, x_sq, views, cdf, degs, inv_total, inv_t, key,
 
 
 @_jit
+@_m.scope("edge_scan")
 def fused_edge_batch(x, x_sq, cdf, degs, inv_total, inv_t, key, hstate=None,
                      *, batch, kind, inv_bw, beta, pairwise, block_size,
                      num_blocks, n, s, exact, use_pallas, interpret, bm,
@@ -512,6 +524,7 @@ def fused_edge_batch(x, x_sq, cdf, degs, inv_total, inv_t, key, hstate=None,
 
 
 @_jit
+@_m.scope("edge_scan")
 def edge_batch_scan(x, x_sq, cdf, degs, inv_total, inv_t, keys, hstate=None,
                     *, batch, kind, inv_bw, beta, pairwise, block_size,
                     num_blocks, n, s, exact, use_pallas, interpret, bm,
@@ -634,6 +647,7 @@ def walk_layout(n: int, block_size: int, num_blocks: int, s: int):
     return wbs, w_blocks, _tuning.walk_samples_per_block(w_blocks, s)
 
 
+@_m.scope("level1")
 def _walk_level1_cache(x, x_sq, key, *, block_size, num_blocks, n, s):
     """Walk-resident compact level-1 subsample (DESIGN.md §14).
 
@@ -676,6 +690,7 @@ def _walk_level1_cache(x, x_sq, key, *, block_size, num_blocks, n, s):
     return x[flat], x_sq[flat], sel, scale
 
 
+@_m.scope("level1")
 def _cached_block_sums(cache, x, src, *, kind, inv_bw, beta, pairwise,
                        block_size, num_blocks, s, precision):
     """Masked level-1 read against the walk-resident cache: one compact
@@ -1160,6 +1175,7 @@ def patch_block_sums(bs, x, src, slots, old_x, new_x, *, kind, inv_bw, beta,
 
 
 @_jit
+@_m.scope("degrees")
 def degree_delta(degs, x, x_sq, slots, old_x, new_x, old_live, new_live, *,
                  kind, inv_bw, beta, pairwise):
     """Incremental Algorithm 4.3 degree update after a mutation batch:

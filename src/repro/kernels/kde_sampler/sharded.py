@@ -66,6 +66,7 @@ from repro.kernels.kde_rowsum.ops import _PAD_OFFSET
 from repro.kernels.kde_sampler import ops as _ops
 from repro.kernels.kde_sampler import ref as _ref
 from repro.obs import counters as _c
+from repro.obs import metrics as _m
 
 TRACE_COUNTS = _ops.TRACE_COUNTS
 
@@ -149,6 +150,7 @@ class _EngineSpec:
             self.blocks_per_shard, dtype=jnp.int32) * self.block_size
         return jnp.clip(self.n - gbase, 0, self.block_size)
 
+    @_m.scope("level1")
     def _raw_sums(self, q, x_l, xsq_l, key, pidx):
         """Uncorrected, unfloored stratified local block sums (the raw
         Definition 1.1 read -- estimators apply their own corrections)."""
@@ -172,6 +174,7 @@ class _EngineSpec:
         s_b = jnp.minimum(sizes_f, float(s))
         return kv.sum(-1) * (sizes_f / jnp.maximum(s_b, 1.0))[None, :]
 
+    @_m.scope("level1")
     def _local_sums(self, q, own, x_l, xsq_l, key, pidx):
         """Masked §2-contract level-1 sums of the local shard: (w, B_p)
         with the self-kernel subtracted from each query's own block, real
@@ -194,6 +197,7 @@ class _EngineSpec:
                                                     _ref.BLOCK_SUM_FLOOR),
                          0.0)
 
+    @_m.scope("level2")
     def _local_draw(self, src, q, qsq, sums_l, key, x_l, xsq_l, pidx):
         """One two-stage collective draw (the §9 schedule: exactly one
         psum).  Returns (nb, prob, T, status) replicated, T = global
@@ -606,6 +610,7 @@ class ShardedBlocks:
         sp = self.spec
 
         def factory():
+            @_m.scope("edge_scan")
             def body(x_l, xsq_l, x_rep, xsq_rep, cdf, degs, inv_total,
                      inv_t, keys):
                 pidx = _flat_index(sp.mesh, sp.axes)
@@ -789,6 +794,7 @@ def _ring_degrees_body(kernel, axes, size: int):
     axis = axes[0] if len(axes) == 1 else axes
     unit_diag = kernel.name in _ref.BUILTIN_KINDS
 
+    @_m.scope("degrees")
     def body(x_l):
         def step(carry, _):
             acc, blk = carry

@@ -12,9 +12,11 @@ Three sub-layers, one import surface:
   trace-time constants or replicated post-psum values.
 * ``obs.metrics`` -- host-side trace spans and a metrics registry:
   ``Timer``/``span`` with mandatory ``block_until_ready`` fencing and
-  ``jax.profiler.TraceAnnotation`` integration, plus counters / gauges /
-  fixed-bucket histograms (deterministic p50/p99).  Near-zero overhead
-  while disabled (module flag, no per-call dict churn).
+  ``jax.profiler.TraceAnnotation`` integration (spans have their own
+  switch and follow the profiler by default), ``scope`` (a
+  ``jax.named_scope`` decorator naming a device layer), plus counters /
+  gauges / fixed-bucket histograms (deterministic p50/p99).  Near-zero
+  overhead while disabled (module flag, no per-call dict churn).
 * ``obs.export`` -- versioned exporters: the JSON-lines metrics stream of
   ``launch/serve.py``, a Prometheus-text dump, and the shared telemetry
   schema block every ``BENCH_*.json`` artifact carries.
@@ -24,12 +26,13 @@ from repro.obs.counters import (COUNTER_SLOTS, WIDTH, counter, fold,
                                 status_of, totals, word)
 from repro.obs.metrics import (Timer, counter_inc, disable, enable, enabled,
                                event, gauge_set, get_registry, histogram,
-                               reset, span)
+                               reset, scope, set_spans, span)
 
 __all__ = [
     "counters", "metrics", "export",
     "WIDTH", "COUNTER_SLOTS", "word", "fold", "status_of", "counter",
     "totals",
-    "Timer", "span", "enable", "disable", "enabled", "reset",
+    "Timer", "span", "scope", "set_spans", "enable", "disable", "enabled",
+    "reset",
     "counter_inc", "gauge_set", "histogram", "event", "get_registry",
 ]

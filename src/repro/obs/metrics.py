@@ -4,9 +4,9 @@ Host-side telemetry with a hard performance contract:
 
 * **Disabled by default, near-zero overhead.**  One module-level flag
   guards every recording call; while disabled, ``counter_inc`` /
-  ``gauge_set`` / ``histogram(...).record`` are a single branch and
-  ``span`` returns a shared no-op context manager -- no dict churn, no
-  allocation on the hot path.
+  ``gauge_set`` / ``histogram(...).record`` are a single branch, and
+  while spans are off ``span`` returns a shared no-op context manager --
+  no dict churn, no allocation on the hot path.
 * **Fenced timing.**  ``Timer`` is the one sanctioned way to time device
   work: it calls ``jax.block_until_ready`` on whatever the timed callable
   returns, so the recorded interval is realized device time, never an
@@ -17,16 +17,26 @@ Host-side telemetry with a hard performance contract:
   edges; p50/p99 are cumulative-count lookups over those buckets, so two
   runs with identical samples report identical quantiles (no
   interpolation of float accumulation order).
-* **xprof integration.**  When enabled, spans open a
-  ``jax.profiler.TraceAnnotation`` so the same names show up on the
-  device timeline under xprof / TensorBoard trace view.
+* **xprof integration.**  Spans have a switch of their own
+  (:func:`set_spans`): while spans are on, ``span(name)`` opens a
+  ``jax.profiler.TraceAnnotation`` so the name shows up on the host
+  timeline of a profiler trace, beside the device operations.  By default
+  spans follow the profiler (on exactly while a trace is being
+  collected); ``set_spans`` pins them on or off, and ``enable()`` turns
+  them on.  :func:`scope` is the device-side twin: a
+  ``jax.named_scope`` around a traced function, so every operation it
+  lowers to carries the layer's name in its op-name metadata.
 """
 from __future__ import annotations
 
 import bisect
+import functools
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
+
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
 
 _enabled = False
 _lock = threading.Lock()
@@ -41,15 +51,33 @@ _events: List[Tuple[str, dict]] = []
 _MAX_EVENTS = 4096
 
 
+#: the spans-only switch: True on, False off, None on while a profiler
+#: trace is being collected
+_spans: Optional[bool] = None
+
+
 def enable() -> None:
-    """Turn the registry on (module-level flag; thread-safe)."""
-    global _enabled
+    """Turn the registry on, and spans with it (module-level flags)."""
+    global _enabled, _spans
     _enabled = True
+    _spans = True
 
 
 def disable() -> None:
-    global _enabled
+    """Turn the registry off; spans return to following the profiler."""
+    global _enabled, _spans
     _enabled = False
+    _spans = None
+
+
+def set_spans(on: Optional[bool]) -> None:
+    """The spans-only switch, independent of the registry: ``True`` opens
+    a ``TraceAnnotation`` per span, ``False`` makes ``span`` the shared
+    no-op, ``None`` opens them only while a profiler trace is being
+    collected.  Span histograms are still recorded only while the
+    registry is enabled."""
+    global _spans
+    _spans = on
 
 
 def enabled() -> bool:
@@ -186,18 +214,19 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Enabled-mode span: xprof TraceAnnotation + elapsed histogram."""
+    """Spans-on span: xprof TraceAnnotation (with ``meta`` as its keyword
+    metadata) + elapsed histogram while the registry is enabled."""
 
-    __slots__ = ("name", "_t0", "_ann")
+    __slots__ = ("name", "meta", "_t0", "_ann")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, meta: dict):
         self.name = name
+        self.meta = meta
         self._ann = None
         self._t0 = 0.0
 
     def __enter__(self):
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = _Annotation(self.name, **self.meta)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -209,10 +238,30 @@ class _Span:
         return False
 
 
-def span(name: str):
-    """``with obs.span("serve.tick"): ...`` -- xprof-annotated timed
-    region; the shared no-op singleton while disabled."""
-    return _Span(name) if _enabled else _NULL_SPAN
+def span(name: str, **meta):
+    """``with obs.span("serve.tick", requests=32): ...`` -- xprof-annotated
+    timed region; ``meta`` (op, request count) rides in the annotation's
+    keyword metadata, so the name stays fixed.  The shared no-op singleton
+    while spans are off."""
+    on = _spans
+    if on is None:
+        on = _Annotation.is_enabled()
+    return _Span(name, meta) if on else _NULL_SPAN
+
+
+def scope(name: str):
+    """Decorator: trace the function inside ``jax.named_scope(name)``, so
+    every device operation it lowers to carries ``name`` in its op-name
+    metadata (``jit(f)/.../level1/...``).  Metadata only: the compiled
+    program is unchanged.  A fresh scope per call (a shared
+    ``named_scope`` object keeps one saved stack and cannot nest)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
 
 
 class Timer:
