@@ -13,8 +13,11 @@ device batches as the static shapes allow:
 * **continuous batching** -- concurrent requests are grouped by
   ``(op, tenant signature, shape bucket)`` and run as ONE program via the
   ``batched_*`` entry points of ``kernels/kde_sampler`` / ``kde_hash``
-  (``jax.vmap`` over the request axis), with per-request PRNG keys and
-  per-request uint32 status words.  Request widths are padded up to a
+  (``jax.vmap`` over the request axis; a one-tenant group's exact Pallas
+  draw or ``prob_of`` read packs all its rows into ONE level-1 pass), with
+  per-request PRNG keys and per-request uint32 status words.  The tick
+  counts those passes (``level1_passes``, ``serve.level1_passes``).
+  Request widths are padded up to a
   static bucket (powers of two by default), so the number of compiled
   programs is bounded by ``len(buckets)`` per (tenant signature, op) --
   not by the workload's request shapes.
@@ -411,7 +414,7 @@ class KernelGraphServable:
         adm0, ev0 = self.admissions, self.evictions
         evals0 = self.device_counters["evals"]
         stats = dict(requests=len(reqs), groups=0, served=0, failed=0,
-                     stale=0)
+                     stale=0, level1_passes=0)
         if not reqs:
             stats.update(admissions=0, evictions=0, tick_ms=0.0,
                          realized_evals=0)
@@ -464,7 +467,7 @@ class KernelGraphServable:
                 if key[0] == "mesh":
                     self._serve_mesh_group(key, grp)
                 else:
-                    self._serve_flat_group(key, grp)
+                    self._serve_flat_group(key, grp, stats)
             except Exception as e:     # noqa: BLE001 -- per-group isolation
                 for r in grp:
                     if r.finished is None:
@@ -488,7 +491,7 @@ class KernelGraphServable:
                 _m.observe(f"serve.latency.{r.tenant}.{r.op}.us",
                            (r.finished - r.submitted) * 1e6)
         for k in ("served", "failed", "stale", "admissions", "evictions",
-                  "realized_evals"):
+                  "realized_evals", "level1_passes"):
             _m.counter_inc(f"serve.{k}", stats[k])
         _m.observe("serve.tick.us", stats["tick_ms"] * 1e3)
         _m.gauge_set("serve.resident", float(len(self._lru)))
@@ -592,15 +595,19 @@ class KernelGraphServable:
             r.result = results[i] if errors[i] is None else None
             r.finished = now
 
-    def _serve_flat_group(self, key, grp) -> None:
+    def _serve_flat_group(self, key, grp, stats) -> None:
         """Serve one (tenant signature, op, bucket) group as ONE padded
-        vmap program over the stacked tenant arena, in four spans:
+        program over the stacked tenant arena, in four spans:
         ``serve.stage`` (arena, padded payloads, keys), ``serve.dispatch``
         (the ``batched_*`` call), ``serve.readback`` (the host blocks on
-        the outputs) and ``serve.scatter``."""
+        the outputs) and ``serve.scatter``.  A draw or ``prob_of`` group
+        adds the Pallas level-1 passes its program makes over the dataset
+        (``ops.level1_passes``) to the tick's ``level1_passes`` and to its
+        ``serve.dispatch`` span as ``passes``."""
         from repro.kernels.kde_sampler import ops as _ops
         op, wb = key[1], key[2]
         meta = dict(op=op, requests=len(grp))
+        passes = None
         with _m.span("serve.stage", **meta):
             names = sorted({r.tenant for r in grp})
             tenants = [self._tenants[nm] for nm in names]
@@ -628,6 +635,8 @@ class KernelGraphServable:
 
                 def lane(out, i, w):
                     return out[0][i, :w], out[1][i, :w]
+                passes = _ops.level1_passes(len(tenants), len(grp), wb,
+                                            **cfg)
             elif op == "walk":
                 length = key[3]
                 widths = [len(np.asarray(r.payload["starts"]).reshape(-1))
@@ -660,6 +669,8 @@ class KernelGraphServable:
 
                 def lane(out, i, w):
                     return out[0][i, :w]
+                passes = _ops.level1_passes(len(tenants), len(grp), wb,
+                                            **cfg)
             elif op == "query":
                 widths = [len(np.atleast_2d(r.payload["y"])) for r in grp]
                 y = np.stack([_pad_pts(r.payload["y"], wb) for r in grp])
@@ -686,8 +697,10 @@ class KernelGraphServable:
                     return out[0][i, :w]
             else:                                      # pragma: no cover
                 raise ValueError(op)
-        with _m.span("serve.dispatch", **meta):
+        dmeta = meta if passes is None else dict(meta, passes=passes)
+        with _m.span("serve.dispatch", **dmeta):
             out, st = call()
+        stats["level1_passes"] += passes or 0
         with _m.span("serve.readback", **meta):
             out = [np.asarray(a) for a in out]
         with _m.span("serve.scatter", **meta):

@@ -31,7 +31,9 @@ Public entry points (all jitted; static config is passed by keyword):
 * ``batched_fused_sample`` / ``batched_walk_scan`` / ``batched_prob_of``
   / ``batched_kde_query``    -- the multi-tenant serving entry points
   (DESIGN.md §13): vmap over a request axis with per-request PRNG keys,
-  per-request status words, and a stacked tenant arena.
+  per-request status words, and a stacked tenant arena; on a one-tenant
+  arena the exact Pallas draw and ``prob_of`` reads pack all requests'
+  rows into ONE level-1 pass (``level1_passes`` counts the passes).
 
 Every sampling / application program additionally returns a ``(obs.WIDTH,)``
 uint32 **counter word** (``repro.obs.counters``, DESIGN.md §15): slot 0 is
@@ -200,6 +202,33 @@ def _pallas_pad(x, src, bm, block_size):
 
 
 @_m.scope("level1")
+def _sample_block_rows(x, src, gumbel, *, kind, inv_bw, beta, block_size,
+                       interpret, bm, precision):
+    """One fused Pallas level-1 pass (block sums + in-pass Gumbel-max
+    draw) over the frontier rows ``src (m,)`` with their ``gumbel (m, B)``
+    noise: the rows fill the pass's ``bm``-row query tiles in order.
+    Returns (blk, p_blk, block sums) of the m real rows."""
+    m = src.shape[0]
+    q, own, xp, rem = _pallas_pad(x, src, bm, block_size)
+    blk, pb, _, bs = _k.sample_block_pallas(
+        q, xp, own, jnp.pad(gumbel, ((0, rem), (0, 0))), kind, inv_bw, beta,
+        bm=bm, bn=block_size, interpret=interpret, precision=precision)
+    return blk[:m], pb[:m], bs[:m]
+
+
+@_m.scope("level1")
+def _masked_rows(x, src, *, kind, inv_bw, beta, block_size, interpret, bm,
+                 precision):
+    """One Pallas masked-blocksum pass (no Gumbel state) over the frontier
+    rows ``src (m,)``: their (m, B) own-block corrected, floored sums."""
+    m = src.shape[0]
+    q, own, xp, _ = _pallas_pad(x, src, bm, block_size)
+    return _k.masked_blocksum_pallas(q, xp, own, kind, inv_bw, beta, bm=bm,
+                                     bn=block_size, interpret=interpret,
+                                     precision=precision)[:m]
+
+
+@_m.scope("level1")
 def _masked_block_sums(x, x_sq, src, key, *, kind, inv_bw, beta, pairwise,
                        block_size, num_blocks, n, s, exact, precision="f32"):
     """Level-1 sums for a frontier of dataset indices, own-block corrected
@@ -301,6 +330,31 @@ def _walk_sample_core(x, x_sq, views, src, bs, key, *, kind, inv_bw, beta,
     return nb, pb * pin
 
 
+def _pallas_noise(k_rest, w, num_blocks):
+    """The fused Pallas draw's randomness for a w-frontier, from the second
+    half of its key split: the (w, B) Gumbel noise of the in-pass block
+    draw and the (w,) uniforms of the level-2 draw."""
+    k_g, k_in = jax.random.split(k_rest)
+    with jax.named_scope("level1"):
+        gumbel = jax.random.gumbel(k_g, (w, num_blocks))
+    return gumbel, jax.random.uniform(k_in, (w,))
+
+
+def _pallas_level2(x, x_sq, views, src, blk, pb, bs, u, *, kind, inv_bw,
+                   beta, pairwise, block_size, n):
+    """The level-2 half of the fused Pallas draw: the drawn block's exact
+    row, the in-block draw with uniforms ``u``, the realized probability
+    and the status of the read and the draw.  Returns (nb, prob, status)."""
+    kv, live, cols_c = _level2_kv(x, x_sq, views, src, blk, kind=kind,
+                                  inv_bw=inv_bw, beta=beta, pairwise=pairwise,
+                                  block_size=block_size, n=n)
+    nb, pin = _level2_draw(kv, live, cols_c, u)
+    prob = pb * pin
+    st = _g.merge(_g.sums_status(bs, _ref.BLOCK_SUM_FLOOR),
+                  _g.result_status(prob))
+    return nb, prob, st
+
+
 def _fused_sample(x, x_sq, src, key, hstate=None, *, kind, inv_bw, beta,
                   pairwise, block_size, num_blocks, n, s, exact, use_pallas,
                   interpret, bm, level1="blocked", num_far=64,
@@ -323,24 +377,15 @@ def _fused_sample(x, x_sq, src, key, hstate=None, *, kind, inv_bw, beta,
         st = _g.merge(st, _g.result_status(prob))
     elif exact and use_pallas:
         # Fully fused level-1: block sums + Gumbel-max draw in one Pallas pass.
-        k_g, k_in = jax.random.split(k_rest)
-        with jax.named_scope("level1"):
-            q, own, xp, rem = _pallas_pad(x, src, bm, block_size)
-            gp = jnp.pad(jax.random.gumbel(k_g, (w, num_blocks)),
-                         ((0, rem), (0, 0)))
-            blk, pb, _, bs = _k.sample_block_pallas(
-                q, xp, own, gp, kind, inv_bw, beta, bm=bm, bn=block_size,
-                interpret=interpret, precision=precision)
-            blk, pb, bs = blk[:w], pb[:w], bs[:w]
-        kv, live, cols_c = _level2_kv(x, x_sq, views, src, blk, kind=kind,
-                                      inv_bw=inv_bw, beta=beta,
+        gumbel, u = _pallas_noise(k_rest, w, num_blocks)
+        blk, pb, bs = _sample_block_rows(
+            x, src, gumbel, kind=kind, inv_bw=inv_bw, beta=beta,
+            block_size=block_size, interpret=interpret, bm=bm,
+            precision=precision)
+        nb, prob, st = _pallas_level2(x, x_sq, views, src, blk, pb, bs, u,
+                                      kind=kind, inv_bw=inv_bw, beta=beta,
                                       pairwise=pairwise,
                                       block_size=block_size, n=n)
-        nb, pin = _level2_draw(kv, live, cols_c,
-                               jax.random.uniform(k_in, (w,)))
-        prob = pb * pin
-        st = _g.merge(_g.sums_status(bs, _ref.BLOCK_SUM_FLOOR),
-                      _g.result_status(prob))
     else:
         bs = _masked_block_sums(x, x_sq, src, k_l1, kind=kind, inv_bw=inv_bw,
                                 beta=beta, pairwise=pairwise,
@@ -450,12 +495,9 @@ def _masked_sums_any(x, x_sq, src, key, hstate=None, *, kind, inv_bw, beta,
             num_blocks=num_blocks, n=n, use_pallas=use_pallas,
             interpret=interpret, bm=bm, precision=precision)
     if exact and use_pallas:
-        w = src.shape[0]
-        q, own, xp, _ = _pallas_pad(x, src, bm, block_size)
-        bs = _k.masked_blocksum_pallas(q, xp, own, kind, inv_bw, beta, bm=bm,
-                                       bn=block_size, interpret=interpret,
-                                       precision=precision)
-        bs = bs[:w]
+        bs = _masked_rows(x, src, kind=kind, inv_bw=inv_bw, beta=beta,
+                          block_size=block_size, interpret=interpret, bm=bm,
+                          precision=precision)
         return bs, _g.sums_status(bs, _ref.BLOCK_SUM_FLOOR)
     bs = _masked_block_sums(x, x_sq, src, key, kind=kind, inv_bw=inv_bw,
                             beta=beta, pairwise=pairwise,
@@ -1007,9 +1049,15 @@ def triangle_edge_scan(x, x_sq, u, v, degs, keys, hstate=None, *, kind,
 # status word.  ``jax.vmap`` over the request axis reduces each lane to
 # the identical op sequence the single-request entry point runs, so lanes
 # match the sequential calls bitwise on the jnp paths (the parity contract
-# ``tests/test_serving.py`` asserts).  All request-axis shapes are padded
-# to static buckets by the serving layer, which bounds recompiles to one
-# program per (tenant signature, op, bucket) group.
+# ``tests/test_serving.py`` asserts).  One exception to the vmap: on a
+# one-tenant arena the exact blocked Pallas read of ``batched_fused_sample``
+# and ``batched_prob_of`` packs every request's frontier rows into the
+# query tiles of ONE level-1 pass (``_packs``), where the vmap would give
+# each request a pass of its own over the whole dataset; the noise, the
+# level-2 draws and the counter words stay per request, so the lanes are
+# unchanged.  Request widths are padded to static buckets by the serving
+# layer, which bounds recompiles to one program per (tenant signature, op,
+# bucket) group.
 # --------------------------------------------------------------------- #
 def tenant_slice(a, ti):
     """One request's slice of a stacked arena leaf.  Runs under vmap, so
@@ -1028,6 +1076,92 @@ def _tenant(xa, xa_sq, hstate, ti):
     return tenant_slice(xa, ti), tenant_slice(xa_sq, ti), hs
 
 
+def _pallas_sweep(exact, use_pallas, level1) -> bool:
+    """True when a draw or ``prob_of`` reads level 1 with the exact
+    blocked Pallas sweep over the whole dataset."""
+    return exact and use_pallas and level1 == "blocked"
+
+
+def _packs(tenants, exact, use_pallas, level1) -> bool:
+    """True when a batched draw or ``prob_of`` program reads level 1 in
+    ONE Pallas pass over all of its requests' rows: the arena holds one
+    tenant (a static shape, as in ``tenant_slice``) and the read is the
+    Pallas sweep, whose row sums do not depend on the other rows of their
+    query tile.  Otherwise each request reads on its own."""
+    return tenants == 1 and _pallas_sweep(exact, use_pallas, level1)
+
+
+def level1_passes(tenants, requests, width, *, exact, use_pallas, level1,
+                  bm, **_):
+    """Pallas level-1 passes over the dataset that one
+    ``batched_fused_sample`` / ``batched_prob_of`` program makes for
+    ``requests`` frontiers of padded ``width`` on an arena of ``tenants``:
+    one per ``bm``-row query tile, ``ceil(R w / bm)`` when the rows pack
+    (``_packs``) and ``R ceil(w / bm)`` when each request has its own
+    tiles; 0 where the read is no Pallas sweep (jnp, stratified, hashed).
+    Takes the sampler's static config as keywords."""
+    if not _pallas_sweep(exact, use_pallas, level1):
+        return 0
+    if _packs(tenants, exact, use_pallas, level1):
+        return -(-requests * width // bm)
+    return requests * -(-width // bm)
+
+
+def _packed_fused_sample(x, x_sq, src, keys, *, kind, inv_bw, beta,
+                         pairwise, block_size, num_blocks, n, interpret, bm,
+                         precision):
+    """``batched_fused_sample`` on a one-tenant arena's exact Pallas path:
+    the R frontiers ``src (R, w)`` share one level-1 pass, whose query
+    tiles hold the R w rows in request order.  Each request's noise comes
+    from its own key exactly as in ``_fused_sample``, and its level-2 draw
+    and counter word are built per request, so lane r equals
+    ``fused_sample`` with key ``keys[r]``."""
+    R, w = src.shape
+    views = _block_views(x, x_sq, block_size)
+    gumbel, u = jax.vmap(
+        lambda k: _pallas_noise(jax.random.split(k)[1], w, num_blocks))(keys)
+    blk, pb, bs = _sample_block_rows(
+        x, src.reshape(R * w), gumbel.reshape(R * w, num_blocks), kind=kind,
+        inv_bw=inv_bw, beta=beta, block_size=block_size, interpret=interpret,
+        bm=bm, precision=precision)
+    blk, pb = blk.reshape(R, w), pb.reshape(R, w)
+    bs = bs.reshape(R, w, num_blocks)
+
+    def level2(src_r, blk_r, pb_r, bs_r, u_r):
+        nb, prob, st = _pallas_level2(x, x_sq, views, src_r, blk_r, pb_r,
+                                      bs_r, u_r, kind=kind, inv_bw=inv_bw,
+                                      beta=beta, pairwise=pairwise,
+                                      block_size=block_size, n=n)
+        return nb, prob, _c.word(status=st, evals=w * (n + block_size),
+                                 l1_reads=w, draws=w)
+
+    nb, prob, cw = jax.vmap(level2)(src, blk, pb, bs, u)
+    return nb, prob, bs, cw
+
+
+def _packed_prob_of(x, x_sq, src, dst, *, kind, inv_bw, beta, pairwise,
+                    block_size, num_blocks, n, interpret, bm, precision):
+    """``batched_prob_of`` on a one-tenant arena's exact Pallas path: one
+    masked level-1 pass over the R w rows of ``src (R, w)``, then each
+    request's exact level-2 probability and counter word."""
+    R, w = src.shape
+    views = _block_views(x, x_sq, block_size)
+    bs = _masked_rows(x, src.reshape(R * w), kind=kind, inv_bw=inv_bw,
+                      beta=beta, block_size=block_size, interpret=interpret,
+                      bm=bm, precision=precision).reshape(R, w, num_blocks)
+
+    def level2(src_r, dst_r, bs_r):
+        prob = _prob_core(x, x_sq, views, src_r, dst_r, bs_r, kind=kind,
+                          inv_bw=inv_bw, beta=beta, pairwise=pairwise,
+                          block_size=block_size, n=n)
+        st = _g.merge(_g.sums_status(bs_r, _ref.BLOCK_SUM_FLOOR),
+                      _g.result_status(prob))
+        return prob, _c.word(status=st, evals=w * (n + block_size),
+                             l1_reads=w)
+
+    return jax.vmap(level2)(src, dst, bs)
+
+
 @_jit
 def batched_fused_sample(xa, xa_sq, tidx, src, keys, hstate=None, *, kind,
                          inv_bw, beta, pairwise, block_size, num_blocks, n,
@@ -1038,8 +1172,14 @@ def batched_fused_sample(xa, xa_sq, tidx, src, keys, hstate=None, *, kind,
     per-request PRNG keys, ``tidx (R,)`` tenant indices.  Returns
     (neighbors (R, w), probs (R, w), level-1 sums (R, w, B), per-request
     counter words (R, obs.WIDTH)).  Lane r is exactly ``fused_sample`` on
-    tenant ``tidx[r]`` with key ``keys[r]``."""
+    tenant ``tidx[r]`` with key ``keys[r]``; on a one-tenant exact Pallas
+    arena the lanes share one level-1 pass (``_packs``)."""
     TRACE_COUNTS["batched_fused_sample"] += 1
+    if _packs(xa.shape[0], exact, use_pallas, level1):
+        return _packed_fused_sample(
+            xa[0], xa_sq[0], src, keys, kind=kind, inv_bw=inv_bw, beta=beta,
+            pairwise=pairwise, block_size=block_size, num_blocks=num_blocks,
+            n=n, interpret=interpret, bm=bm, precision=precision)
 
     def one(ti, src_r, key_r):
         x, x_sq, hs = _tenant(xa, xa_sq, hstate, ti)
@@ -1088,9 +1228,15 @@ def batched_prob_of(xa, xa_sq, tidx, src, dst, keys, hstate=None, *, kind,
     """q(dst | src) for R requests (``src``/``dst`` (R, w)) in ONE
     program: per lane one masked level-1 read of the src frontier (the
     same read ``prob_of`` performs when its cache is cold) followed by the
-    exact level-2 probability.  Returns (probs (R, w), counter words
-    (R, obs.WIDTH))."""
+    exact level-2 probability; on a one-tenant exact Pallas arena one
+    read covers every lane (``_packs``).  Returns (probs (R, w), counter
+    words (R, obs.WIDTH))."""
     TRACE_COUNTS["batched_prob_of"] += 1
+    if _packs(xa.shape[0], exact, use_pallas, level1):
+        return _packed_prob_of(
+            xa[0], xa_sq[0], src, dst, kind=kind, inv_bw=inv_bw, beta=beta,
+            pairwise=pairwise, block_size=block_size, num_blocks=num_blocks,
+            n=n, interpret=interpret, bm=bm, precision=precision)
 
     def one(ti, src_r, dst_r, key_r):
         x, x_sq, hs = _tenant(xa, xa_sq, hstate, ti)

@@ -20,7 +20,9 @@ import subproc
 from repro.core.kernels_fn import gaussian
 from repro.core.serving import (DEFAULT_BUCKETS, KernelGraphServable,
                                 shape_bucket)
+from repro.ft import guards as _g
 from repro.kernels.kde_sampler import ops as _ops
+from repro.obs import counters as _c
 
 N, D = 192, 4
 
@@ -151,6 +153,124 @@ def test_hash_tenants_bitwise_sample_and_query():
                                   hq.state, jax.random.PRNGKey(13),
                                   **hq._cfg)
     np.testing.assert_array_equal(rq.result, np.asarray(e0))
+
+
+# ------------------------------------------------------------------- #
+# the packed level-1 pass of one-tenant exact Pallas groups
+# ------------------------------------------------------------------- #
+def _pallas_grids(fn, *args, **kw):
+    """The grid of every ``pallas_call`` in ``fn``'s traced program."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args).jaxpr))
+
+
+def _exact_pallas_cfg(bm):
+    return dict(kind="gaussian", inv_bw=1.0, beta=1.0, pairwise=None,
+                block_size=16, num_blocks=N // 16, n=N, s=8, exact=True,
+                use_pallas=True, interpret=True, bm=bm, level1="blocked",
+                num_far=64, precision="f32")
+
+
+@pytest.mark.parametrize("R,w,bm", [(3, 5, 8), (4, 8, 8)])
+def test_packed_level1_pass_matches_per_request_reads(R, w, bm):
+    """On a one-tenant arena the exact Pallas draw and ``prob_of``
+    programs read level 1 in ONE pass over all R w rows (grid
+    ``ceil(R w / bm)`` row tiles, a request's rows may straddle two), and
+    every lane equals the per-request program bit for bit: neighbours,
+    probabilities, block sums and counter words."""
+    x = jnp.asarray(_data("packed"))
+    x_sq = jnp.sum(x * x, -1)
+    xa, xa_sq = x[None], x_sq[None]
+    cfg = _exact_pallas_cfg(bm)
+    rng = np.random.default_rng(stats.derive_seed("serving", "packed", R))
+    src = rng.integers(0, N, size=(R, w)).astype(np.int32)
+    dst = ((src + rng.integers(1, N, size=(R, w))) % N).astype(np.int32)
+    seeds = [int(v) for v in rng.integers(0, 2**31, size=R)]
+    keys = np.stack([np.asarray(jax.random.PRNGKey(v)) for v in seeds])
+    tidx = np.zeros(R, np.int32)
+    tiles = (-(-R * w // bm), cfg["num_blocks"])
+    assert _pallas_grids(_ops.batched_fused_sample, xa, xa_sq, tidx, src,
+                         keys, **cfg) == [tiles]
+    assert _pallas_grids(_ops.batched_prob_of, xa, xa_sq, tidx, src, dst,
+                         keys, **cfg) == [tiles]
+    nb, prob, bs, cw = _ops.batched_fused_sample(xa, xa_sq, tidx, src, keys,
+                                                 **cfg)
+    pq, pcw = _ops.batched_prob_of(xa, xa_sq, tidx, src, dst, keys, **cfg)
+    l2 = {k: cfg[k] for k in ("kind", "inv_bw", "beta", "pairwise",
+                              "block_size", "n")}
+    for r in range(R):
+        key = jax.random.PRNGKey(seeds[r])
+        s_r, d_r = jnp.asarray(src[r]), jnp.asarray(dst[r])
+        for got, want in zip((nb[r], prob[r], bs[r], cw[r]),
+                             _ops.fused_sample(x, x_sq, s_r, key, **cfg)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        bs0, w1 = _ops.masked_block_sums(x, x_sq, s_r, key, **cfg)
+        p0, w2 = _ops.prob_of_from_block_sums(x, x_sq, s_r, d_r, bs0, **l2)
+        np.testing.assert_array_equal(np.asarray(pq[r]), np.asarray(p0))
+        np.testing.assert_array_equal(
+            np.asarray(pcw[r]),
+            np.asarray(_c.fold_status(_c.fold(w1, w2),
+                                      _g.result_status(p0))))
+
+
+def test_level1_pass_dispatch_rule_and_counter(monkeypatch):
+    """Both sides of the dispatch rule through the servable, with Pallas
+    on (interpreted): a one-tenant group packs its rows into one pass, a
+    two-tenant group reads per request; ``serve.level1_passes`` and the
+    tick's ``level1_passes`` count ``ceil(R wb / bm)`` and
+    ``R ceil(wb / bm)``, and the served lanes equal the sequential calls."""
+    from repro.kernels import platform
+    from repro.obs import metrics as M
+
+    monkeypatch.setattr(platform, "resolve", lambda *a, **k: (True, True))
+    srv = KernelGraphServable(max_resident=4)
+    for name, shift, seed in (("a", 0.0, 3), ("b", 0.8, 4)):
+        srv.add_tenant(name, _data(name, shift), gaussian(1.0),
+                       block_size=16, exact_blocks=True, seed=seed)
+    cfg = _cfg(srv, "a")
+    assert cfg["use_pallas"] and cfg["interpret"] and cfg["bm"] == 128
+    src, wb = np.arange(16), 16
+    M.reset()
+    M.enable()
+    try:
+        one = [srv.submit("a", "sample", src=src + 8 * i, seed=500 + i)
+               for i in range(3)]
+        one.append(srv.submit("a", "prob_of", src=src, dst=(src + 5) % N,
+                              seed=510))
+        st1 = srv.tick()
+        two = [srv.submit(nm, "sample", src=src + 8 * i, seed=520 + i)
+               for i, nm in enumerate("aab")]
+        st2 = srv.tick()
+        counted = M.get_registry()["counters"]["serve.level1_passes"]
+    finally:
+        M.disable()
+        M.reset()
+    assert st1["failed"] == st2["failed"] == 0
+    assert st1["level1_passes"] == -(-3 * wb // 128) + 1 == 2
+    assert st2["level1_passes"] == 3 * -(-wb // 128) == 3
+    assert counted == 5
+    tidx, keys = np.zeros(3, np.int32), np.zeros((3, 2), np.uint32)
+    srcs = np.zeros((3, wb), np.int32)
+    xa1 = jnp.stack([srv.tenant("a").admit().x])
+    xa2 = jnp.stack([srv.tenant(nm).admit().x for nm in "aab"])
+    grid1 = _pallas_grids(_ops.batched_fused_sample, xa1,
+                          jnp.sum(xa1 * xa1, -1), tidx, srcs, keys, **cfg)
+    grid2 = _pallas_grids(_ops.batched_fused_sample, xa2,
+                          jnp.sum(xa2 * xa2, -1), tidx, srcs, keys, **cfg)
+    nbk = cfg["num_blocks"]
+    assert grid1 == [(1, nbk)] and grid2 == [(3, 1, nbk)]
+    for r, nm in zip(one[:3] + two, "aaa" + "aab"):
+        nbr = srv.tenant(nm).admit()
+        nb0, p0, _, _ = _ops.fused_sample(
+            nbr.x, nbr.x_sq, jnp.asarray(r.payload["src"], jnp.int32),
+            jax.random.PRNGKey(r.seed), **nbr._cfg)
+        np.testing.assert_array_equal(r.result[0], np.asarray(nb0))
+        np.testing.assert_array_equal(r.result[1], np.asarray(p0))
 
 
 # ------------------------------------------------------------------- #

@@ -84,3 +84,26 @@ def test_kernel_compiles_for_v5e(one_chip, name, d, precision):
               else _shape(one_chip, s) for s in specs]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["batched_fused_sample", "batched_prob_of"])
+def test_packed_served_programs_compile_for_v5e(one_chip, op):
+    """The served draw and ``prob_of`` programs on a one-tenant arena at
+    the SIFT1M widths (10^6 x 128 in 1000-row blocks, 8 requests of 16
+    rows): the rows of every request share ONE Pallas level-1 call, and
+    the program keeps no per-request copy of the tenant."""
+    from repro.kernels.kde_sampler import ops
+    n, d, bs, r, w = 1_000_000, 128, 1000, 8, 16
+    cfg = dict(kind="gaussian", inv_bw=INV_BW, beta=1.0, pairwise=None,
+               block_size=bs, num_blocks=n // bs, n=n, s=16, exact=True,
+               use_pallas=True, interpret=False, bm=128, level1="blocked",
+               num_far=64, precision="f32")
+    rows = _shape(one_chip, (r, w), jnp.int32)
+    args = [_shape(one_chip, (1, n, d)), _shape(one_chip, (1, n)),
+            _shape(one_chip, (r,), jnp.int32), rows]
+    if op == "batched_prob_of":
+        args.append(rows)
+    args.append(_shape(one_chip, (r, 2), jnp.uint32))
+    compiled = getattr(ops, op).lower(*args, **cfg).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
