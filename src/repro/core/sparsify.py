@@ -35,6 +35,7 @@ class SparseGraph:
     kde_queries: int = 0
     kernel_evals: int = 0
     device_evals: int = 0     # the same count, from the counter words
+    device_psums: int = 0     # collective psums, from the counter words
 
     @property
     def num_edges(self) -> int:
@@ -119,15 +120,19 @@ def _sparsify(x, kernel, t, estimator, seed, batch, exact_blocks,
         deg = DegreeSampler(est, seed=seed + 1,
                             mesh=mesh if est is nbr.blocks else None)
         cdf, degs, total = deg.cdf_device, deg.degrees_device, deg.total
-    with _m.span("sparsify.edges"):
+    # the mesh engine's schedule: one psum per edge batch (DESIGN.md §9)
+    shards = nbr.blocks.engine.num_shards if mesh is not None else 1
+    psums = max(-(-t // batch), 1) if mesh is not None else 0
+    with _m.span("sparsify.edges", psums=psums, shards=shards):
         u, v, w, _, _ = nbr.edge_batches(cdf, degs, total, t, batch=batch)
     with _m.span("sparsify.graph"):
         g = SparseGraph(n, np.asarray(u, np.int64), np.asarray(v, np.int64),
                         np.asarray(w, np.float64))
         g.kernel_evals = nbr.evals + (0 if est is nbr.blocks else est.evals)
         est_words = getattr(est, "device_counters", None)
-        g.device_evals = nbr.device_counters["evals"] + (
-            est_words["evals"] if est_words is not None else 0)
+        g.device_evals, g.device_psums = (
+            nbr.device_counters[k] + (est_words[k] if est_words is not None
+                                      else 0) for k in ("evals", "psums"))
     # degree preprocessing + one forward level-1 read per drawn edge (the
     # reverse probability collapses onto the preprocessed degrees)
     drawn = ((t + batch - 1) // batch) * batch

@@ -243,6 +243,26 @@ def inverse_cdf_index(cdf, u) -> jnp.ndarray:
     return jnp.clip(idx, 0, cdf.shape[0] - 1).astype(jnp.int32)
 
 
+def inverse_cdf_pick(w, u):
+    """Inverse-CDF draw per row of nonnegative weights ``w`` (m, k) at
+    uniforms ``u`` (m,): the first entry of positive weight whose prefix
+    sum reaches ``u * total``, ``total`` being the last prefix sum.  The
+    pick never lands on an entry of zero weight, however the sums round
+    (a total summed apart from the prefix sums, or a prefix sum taken by
+    a tree, as a TPU takes it, can exceed the prefix sum at the last
+    positive entry, and ``u * total`` then passes it).  Returns ``(index,
+    total)``; a row of zeros picks 0."""
+    k = w.shape[1]
+    c = jnp.cumsum(w, axis=1)
+    tot = c[:, -1]
+    pos = w > 0
+    hit = (c >= (u * tot)[:, None]) & pos
+    col = jnp.arange(k, dtype=jnp.int32)[None, :]
+    first = jnp.min(jnp.where(hit, col, k), axis=1)
+    last = jnp.max(jnp.where(pos, col, 0), axis=1)
+    return jnp.where(first < k, first, last), tot
+
+
 def block_views(x, x_sq, block_size: int):
     """(B, bs, d) / (B, bs) contiguous views of the (padded) dataset.
     Built once per compiled program; the level-2 read then gathers whole
@@ -280,12 +300,12 @@ def level2_row(x, x_sq, views, src, blk, kind: str, inv_bw: float,
 def level2_draw(kv, live, cols_c, u2):
     """Inverse-CDF draw from each row of ``kv``; all-zero rows (numerically
     underflowed blocks) fall back to uniform over the live columns instead
-    of producing NaN."""
+    of producing NaN.  The pick never lands on a dead column (the self
+    edge, a ragged tail block's out-of-range columns), however the prefix
+    sums round (``inverse_cdf_pick``)."""
     rowsum = kv.sum(axis=1)
     use = jnp.where((rowsum > 0.0)[:, None], kv, live.astype(jnp.float32))
-    c = jnp.cumsum(use, axis=1)
-    tot = c[:, -1]
-    j = jnp.sum((u2 * tot)[:, None] > c, axis=1).clip(0, kv.shape[1] - 1)
+    j, tot = inverse_cdf_pick(use, u2)
     nb = jnp.take_along_axis(cols_c, j[:, None], axis=1)[:, 0]
     pin = jnp.take_along_axis(use, j[:, None], axis=1)[:, 0] \
         / jnp.maximum(tot, 1e-30)
@@ -550,18 +570,11 @@ def sharded_sample_from_sums_ref(x_pad, x_sq_pad, views, src, sums, key,
     num_shards = num_blocks_pad // blocks_per_shard
     k_shard, k_blk, k_in = jax.random.split(key, 3)
     by_shard = sums.reshape(w, num_shards, blocks_per_shard)
-    t = by_shard.sum(-1)                                  # (w, P)
-    ct = jnp.cumsum(t, axis=1)
-    tot = ct[:, -1]
-    u0 = jax.random.uniform(k_shard, (w,))
-    owner = jnp.sum((u0 * tot)[:, None] > ct, axis=1).clip(0, num_shards - 1)
+    t = jnp.cumsum(by_shard, axis=-1)[..., -1]            # (w, P)
+    owner, tot = inverse_cdf_pick(t, jax.random.uniform(k_shard, (w,)))
     local = jnp.take_along_axis(by_shard, owner[:, None, None],
                                 axis=1)[:, 0]             # (w, B_p)
-    t_o = jnp.take_along_axis(t, owner[:, None], axis=1)[:, 0]
-    c = jnp.cumsum(local, axis=1)
-    u1 = jax.random.uniform(k_blk, (w,))
-    blk_l = jnp.sum((u1 * t_o)[:, None] > c, axis=1).clip(
-        0, blocks_per_shard - 1).astype(jnp.int32)
+    blk_l, _ = inverse_cdf_pick(local, jax.random.uniform(k_blk, (w,)))
     s_b = jnp.take_along_axis(local, blk_l[:, None], axis=1)[:, 0]
     gblk = (owner * blocks_per_shard).astype(jnp.int32) + blk_l
     kv, live, cols_c = level2_row(x_pad, x_sq_pad, views, src, gblk, kind,

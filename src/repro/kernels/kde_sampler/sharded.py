@@ -207,11 +207,10 @@ class _EngineSpec:
         w = src.shape[0]
         bl, bs = self.blocks_per_shard, self.block_size
         k_shard, k_blk, k_in = jax.random.split(key, 3)
-        t_l = sums_l.sum(axis=1)
-        c = jnp.cumsum(sums_l, axis=1)
-        u1 = jax.random.uniform(k_blk, (w,))
-        blk_l = jnp.sum((u1 * t_l)[:, None] > c, axis=1).clip(
-            0, bl - 1).astype(jnp.int32)
+        # the all-sentinel blocks at the tail weigh 0: the pick never
+        # lands there, however the sums round (ref.inverse_cdf_pick)
+        blk_l, t_l = _ref.inverse_cdf_pick(
+            sums_l, jax.random.uniform(k_blk, (w,)))
         s_b = jnp.take_along_axis(sums_l, blk_l[:, None], axis=1)[:, 0]
         xb = x_l.reshape(bl, bs, self.d)[blk_l]
         xbsq = xsq_l.reshape(bl, bs)[blk_l]
@@ -234,11 +233,8 @@ class _EngineSpec:
         allp = jax.lax.psum(payload[:, None, :] * oh[None, :, None],
                             self.axes)                            # (w, P, 3)
         t_all, q_all = allp[..., 0], allp[..., 1]
-        ct = jnp.cumsum(t_all, axis=1)
-        tot = ct[:, -1]
-        u0 = jax.random.uniform(k_shard, (w,))
-        owner = jnp.sum((u0 * tot)[:, None] > ct, axis=1).clip(
-            0, self.num_shards - 1)
+        owner, tot = _ref.inverse_cdf_pick(
+            t_all, jax.random.uniform(k_shard, (w,)))
         nb = (owner * self.shard_size + jnp.take_along_axis(
             allp[..., 2], owner[:, None], axis=1)[:, 0].astype(jnp.int32))
         prob = jnp.take_along_axis(q_all, owner[:, None], axis=1)[:, 0] \
@@ -614,11 +610,17 @@ class ShardedBlocks:
             def body(x_l, xsq_l, x_rep, xsq_rep, cdf, degs, inv_total,
                      inv_t, keys):
                 pidx = _flat_index(sp.mesh, sp.axes)
+                # every batch's u-draw depends on its key alone, so all of
+                # them run before the scan, as one inverse-CDF lookup: the
+                # loop body keeps the level-1 sweep and the collective draw
+                # (the same values as drawing u in the loop)
+                k_u, k_fwd = jnp.moveaxis(jax.vmap(jax.random.split)(keys),
+                                          1, 0)
+                us = _ref.inverse_cdf_index(cdf, jax.vmap(
+                    lambda k: jax.random.uniform(k, (batch,)))(k_u))
 
-                def step(st, k):
-                    k_u, k_fwd = jax.random.split(k)
-                    u = _ref.inverse_cdf_index(
-                        cdf, jax.random.uniform(k_u, (batch,)))
+                def step(st, xs):
+                    u, k_fwd = xs
                     q = x_rep[u]
                     qsq = xsq_rep[u]
                     k_l1, k_rest = jax.random.split(k_fwd)
@@ -636,7 +638,7 @@ class ShardedBlocks:
                     st = st | st_b | _g.result_status(wgt, q_vu)
                     return st, (u, v, wgt, q_uv, q_vu)
 
-                st, out = jax.lax.scan(step, jnp.uint32(0), keys)
+                st, out = jax.lax.scan(step, jnp.uint32(0), (us, k_fwd))
                 return out + (st,)
             return self._build("sharded_edge_batch_scan", body,
                                self._specs4() + (P(), P(), P(), P(), P()),

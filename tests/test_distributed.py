@@ -153,6 +153,158 @@ print("CONTRACT_BITWISE_OK")
     assert "CONTRACT_BITWISE_OK" in out
 
 
+@pytest.mark.parametrize("case", ["past_every_prefix", "at_zero",
+                                  "random"])
+def test_inverse_cdf_pick_never_lands_on_zero_weight(case):
+    """The mesh draw's shard and block picks: rows like a last shard's
+    block sums (positive real blocks, zero-weight sentinel blocks at the
+    tail, a zero in the middle).  A threshold past every prefix sum -- as
+    rounding makes it when the total is summed apart from the prefix sums,
+    or by a tree -- picks the last positive entry, a zero threshold the
+    first positive one, and elsewhere the pick is the plain inverse CDF."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.kde_sampler import ref
+    rng = np.random.default_rng(5)
+    w = rng.uniform(20.0, 200.0, (512, 12)).astype(np.float32)
+    w[:, 10:] = 0.0
+    w[:, 0] = np.where(np.arange(512) % 2, 0.0, w[:, 0])
+    w[:, 4] = 0.0
+    w[-1] = 0.0                                  # an empty shard picks 0
+    u = {"past_every_prefix": np.full(512, 1.0 + 1e-6, np.float32),
+         "at_zero": np.zeros(512, np.float32),
+         "random": rng.uniform(size=512).astype(np.float32)}[case]
+    j, tot = ref.inverse_cdf_pick(jnp.asarray(w), jnp.asarray(u))
+    j, tot = np.asarray(j), np.asarray(tot)
+    assert np.all(w[np.arange(511), j[:-1]] > 0) and j[-1] == 0
+    c = np.cumsum(w, axis=1)
+    np.testing.assert_array_equal(tot, c[:, -1])
+    if case == "past_every_prefix":
+        np.testing.assert_array_equal(j[:-1], 9)
+    elif case == "at_zero":
+        np.testing.assert_array_equal(j[:-1], np.where(
+            np.arange(511) % 2, 1, 0))
+    else:
+        plain = np.sum((u * tot)[:, None] > c, axis=1)
+        np.testing.assert_array_equal(j[:-1], plain[:-1])
+
+
+def test_sharded_draw_never_returns_a_zero_mass_block():
+    """The collective draw on a layout whose last shard ends in
+    all-sentinel blocks (n = 1,000 in blocks of 4 over 4 shards: 61 real
+    blocks and 2 sentinel ones in the last), all mass in that shard, and
+    a key whose block uniform for row 904 is the largest one JAX draws.  The
+    rows are ones whose summed total rounds above their last real prefix
+    sum: a pick against that total lands on a sentinel block, a draw of
+    probability 0 at row n - 1.  Every draw must have positive mass."""
+    out = _run("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.kernels_fn import gaussian
+from repro.kernels.kde_sampler.sharded import ShardedBlocks
+mesh = make_mesh((4,), ("data",))
+x = np.random.default_rng(1).normal(0, 0.5, (1000, 2)).astype(np.float32)
+eng = ShardedBlocks(mesh, x, gaussian(0.7), block_size=4, exact=True)
+assert (eng.blocks_per_shard, eng.n_pad) == (63, 1008)
+key = jax.random.fold_in(jax.random.PRNGKey(0), 12324)
+u1 = jax.random.uniform(jax.random.split(key, 3)[1], (1024,))
+assert float(u1[904]) == 1.0 - 2.0 ** -23         # the largest it draws
+rows = np.random.default_rng(0).uniform(20, 200, (4096, 63)).astype(np.float32)
+rows[:, 61:] = 0.0
+sums = np.full((1024, 252), 1e-12, np.float32)
+sums[:, 189:] = rows[:1024]
+for i in (422, 436, 587, 748, 756):
+    sums[904, 189:] = rows[i]
+    nb, prob, _ = eng.sample_from_block_sums(
+        jnp.zeros(1024, jnp.int32), jax.device_put(
+            jnp.asarray(sums), NamedSharding(mesh, P(None, "data"))), key)
+    assert np.all(np.asarray(prob) > 0), (i, float(prob[904]))
+    assert int(nb[904]) < 1000
+print("POSITIVE_OK")
+""", devices=4)
+    assert "POSITIVE_OK" in out
+
+
+@pytest.mark.parametrize("case", ["past_every_prefix", "random"])
+def test_level2_draw_never_picks_a_dead_column(case):
+    """The in-block draw over rows like the mesh cell's ragged tail block
+    (28 real columns, 334 out-of-range ones) with the self column dead in
+    half the rows and some rows underflowed to zero (uniform over the live
+    columns).  A threshold past every prefix sum picks the last live
+    column; elsewhere the pick and its probability are bitwise the plain
+    inverse CDF's."""
+    import jax.numpy as jnp
+    from repro.kernels.kde_sampler import ref
+    rng = np.random.default_rng(3)
+    m, bs, real = 256, 362, 28
+    live = np.zeros((m, bs), bool)
+    live[:, :real] = True
+    live[::2, 5] = False                              # the self edge
+    kv = np.where(live, rng.uniform(1e-3, 1.0, (m, bs)), 0.0).astype(
+        np.float32)
+    kv[::7] = 0.0                                     # underflowed rows
+    cols = np.minimum(np.arange(bs), real - 1)[None].repeat(m, 0) + 4096
+    u = (np.full(m, 1.0 + 1e-6, np.float32) if case == "past_every_prefix"
+         else rng.uniform(size=m).astype(np.float32))
+    nb, pin = ref.level2_draw(jnp.asarray(kv), jnp.asarray(live),
+                              jnp.asarray(cols.astype(np.int32)),
+                              jnp.asarray(u))
+    nb, pin = np.asarray(nb), np.asarray(pin)
+    assert np.all(pin > 0)
+    use = np.where(kv.sum(1, keepdims=True) > 0, kv, live.astype(np.float32))
+    c = np.asarray(jnp.cumsum(jnp.asarray(use), axis=1))
+    if case == "past_every_prefix":
+        np.testing.assert_array_equal(nb, 4096 + real - 1)
+    else:
+        j = np.sum((u * c[:, -1])[:, None] > c, axis=1).clip(0, bs - 1)
+        np.testing.assert_array_equal(nb, cols[np.arange(m), j])
+        np.testing.assert_array_equal(
+            pin, use[np.arange(m), j] / np.maximum(c[:, -1], 1e-30))
+
+
+def test_sharded_in_block_draw_never_lands_on_a_dead_column():
+    """The collective draw on a layout whose last real block is ragged (n
+    = 1,000 in blocks of 32 over 4 shards: block 31 holds 8 real rows and
+    24 sentinel ones), all mass in that block, with prefix sums that round
+    upward along a row, as a tree sum can (emulated: the k-th prefix sum
+    scaled by 1 + k 1e-4, so the total tops the last live prefix sum).
+    The plain inverse CDF then lands on the block's out-of-range columns;
+    every draw must stay on a live column of positive probability."""
+    out = _run("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+cumsum = jnp.cumsum
+def rounding_up(a, axis=None, **kw):
+    c = cumsum(a, axis=axis, **kw)
+    k = jnp.arange(c.shape[axis], dtype=c.dtype)
+    return c * (1.0 + 1e-4 * jnp.expand_dims(k, tuple(
+        i for i in range(c.ndim) if i != axis % c.ndim)))
+jnp.cumsum = rounding_up
+from repro.core.kernels_fn import gaussian
+from repro.kernels.kde_sampler.sharded import ShardedBlocks
+mesh = make_mesh((4,), ("data",))
+x = np.random.default_rng(1).normal(0, 0.5, (1000, 2)).astype(np.float32)
+eng = ShardedBlocks(mesh, x, gaussian(0.7), block_size=32, exact=True)
+assert (eng.blocks_per_shard, eng.n_pad) == (8, 1024)
+src = np.arange(1024, dtype=np.int32) % 1000
+sums = np.full((1024, 32), 1e-12, np.float32)
+sums[:, 31] = 1.0
+key = jax.random.PRNGKey(7)
+# the emulated rounding does send the plain pick past the live columns
+u = np.asarray(jax.random.uniform(jax.random.split(key, 3)[2], (1024,)))
+c = np.asarray(rounding_up(jnp.ones((1024, 32)).at[:, 8:].set(0.0), axis=1))
+assert np.sum(np.sum((u * c[:, -1])[:, None] > c, axis=1) >= 8) > 0
+nb, prob, _ = eng.sample_from_block_sums(
+    jnp.asarray(src), jax.device_put(
+        jnp.asarray(sums), NamedSharding(mesh, P(None, "data"))), key)
+nb, prob = np.asarray(nb), np.asarray(prob)
+assert np.all(prob > 0), np.sum(prob <= 0)
+assert np.all((nb >= 992) & (nb < 1000) & (nb != src))
+print("LIVE_OK")
+""", devices=4)
+    assert "LIVE_OK" in out
+
+
 def test_sharded_engine_oracle_schedule_and_no_retrace():
     """The ShardedBlocks engine: (a) draws/walks reproduce the ref.py
     oracles bit-for-bit on both level-1 paths, (b) the collective schedule
@@ -304,9 +456,21 @@ x = rng.normal(0, 0.35, (300, 5)).astype(np.float32)
 ker = gaussian(2.0)
 k = np.asarray(ker.matrix(jnp.asarray(x)), np.float64)
 
+from repro.obs import metrics as M
+edge_spans, span = [], M.span
+def noting(name, **meta):
+    if name == "sparsify.edges":
+        edge_spans.append(meta)
+    return span(name, **meta)
+M.span = noting
 g1 = spectral_sparsify(x, ker, 3000, estimator="exact", exact_blocks=True, seed=0)
 g2 = spectral_sparsify(x, ker, 3000, estimator="exact", exact_blocks=True, seed=0, mesh=mesh)
+M.span = span
 assert (g1.kernel_evals, g1.kde_queries) == (g2.kernel_evals, g2.kde_queries)
+# one psum per edge batch of 1024 on the mesh (the ring adds none), none
+# on one device: from the counter words and on the edge scan's span
+assert (g1.device_psums, g2.device_psums) == (0, 3)
+assert edge_spans == [dict(psums=0, shards=1), dict(psums=3, shards=8)]
 lt = np.diag(k.sum(1) - 1) - (k - np.eye(300))
 err = np.linalg.norm(g2.laplacian_dense() - lt) / np.linalg.norm(lt)
 assert err < 0.5, err
