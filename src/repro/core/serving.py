@@ -14,10 +14,10 @@ device batches as the static shapes allow:
   ``(op, tenant signature, shape bucket)`` and run as ONE program via the
   ``batched_*`` entry points of ``kernels/kde_sampler`` / ``kde_hash``
   (``jax.vmap`` over the request axis; a one-tenant group's exact Pallas
-  draw or ``prob_of`` read packs all its rows into ONE level-1 pass), with
-  per-request PRNG keys and per-request uint32 status words.  The tick
-  counts those passes (``level1_passes``, ``serve.level1_passes``).
-  Request widths are padded up to a
+  draw, ``prob_of`` or query read packs all its rows into ONE level-1
+  pass), with per-request PRNG keys and per-request uint32 status
+  words.  The tick counts those passes (``level1_passes``,
+  ``serve.level1_passes``).  Request widths are padded up to a
   static bucket (powers of two by default), so the number of compiled
   programs is bounded by ``len(buckets)`` per (tenant signature, op) --
   not by the workload's request shapes.
@@ -600,10 +600,11 @@ class KernelGraphServable:
         program over the stacked tenant arena, in four spans:
         ``serve.stage`` (arena, padded payloads, keys), ``serve.dispatch``
         (the ``batched_*`` call), ``serve.readback`` (the host blocks on
-        the outputs) and ``serve.scatter``.  A draw or ``prob_of`` group
-        adds the Pallas level-1 passes its program makes over the dataset
-        (``ops.level1_passes``) to the tick's ``level1_passes`` and to its
-        ``serve.dispatch`` span as ``passes``."""
+        the outputs) and ``serve.scatter``.  A draw, ``prob_of`` or dense
+        query group adds the Pallas level-1 passes its program makes over
+        the dataset (``ops.level1_passes``) to the tick's
+        ``level1_passes`` and to its ``serve.dispatch`` span as
+        ``passes``."""
         from repro.kernels.kde_sampler import ops as _ops
         op, wb = key[1], key[2]
         meta = dict(op=op, requests=len(grp))
@@ -685,6 +686,7 @@ class KernelGraphServable:
                 else:
                     qkeys = ("kind", "inv_bw", "beta", "pairwise",
                              "block_size", "num_blocks", "n", "s", "exact",
+                             "use_pallas", "interpret", "bm", "level1",
                              "precision")
 
                     def call():
@@ -692,6 +694,10 @@ class KernelGraphServable:
                             xa, xa_sq, tidx, y, keys,
                             **{k: cfg[k] for k in qkeys})
                         return (est,), st
+                    # a query reads level 1 in Pallas only where it packs:
+                    # a several-tenant group keeps the per-request jnp sweep
+                    passes = (_ops.level1_passes(1, len(grp), wb, **cfg)
+                              if len(tenants) == 1 else 0)
 
                 def lane(out, i, w):
                     return out[0][i, :w]

@@ -32,8 +32,9 @@ Public entry points (all jitted; static config is passed by keyword):
   / ``batched_kde_query``    -- the multi-tenant serving entry points
   (DESIGN.md §13): vmap over a request axis with per-request PRNG keys,
   per-request status words, and a stacked tenant arena; on a one-tenant
-  arena the exact Pallas draw and ``prob_of`` reads pack all requests'
-  rows into ONE level-1 pass (``level1_passes`` counts the passes).
+  arena the exact Pallas draw, ``prob_of`` and query reads pack all
+  requests' rows into ONE level-1 pass (``level1_passes`` counts the
+  passes).
 
 Every sampling / application program additionally returns a ``(obs.WIDTH,)``
 uint32 **counter word** (``repro.obs.counters``, DESIGN.md §15): slot 0 is
@@ -63,6 +64,7 @@ import jax.numpy as jnp
 
 from repro.ft import guards as _g
 from repro.kernels import tuning as _tuning
+from repro.kernels.kde_rowsum import kernel as _rk
 from repro.kernels.kde_rowsum.ops import _PAD_OFFSET, _pad_rows
 from repro.kernels.kde_sampler import kernel as _k
 from repro.kernels.kde_sampler import ref as _ref
@@ -1050,14 +1052,15 @@ def triangle_edge_scan(x, x_sq, u, v, degs, keys, hstate=None, *, kind,
 # the identical op sequence the single-request entry point runs, so lanes
 # match the sequential calls bitwise on the jnp paths (the parity contract
 # ``tests/test_serving.py`` asserts).  One exception to the vmap: on a
-# one-tenant arena the exact blocked Pallas read of ``batched_fused_sample``
-# and ``batched_prob_of`` packs every request's frontier rows into the
-# query tiles of ONE level-1 pass (``_packs``), where the vmap would give
-# each request a pass of its own over the whole dataset; the noise, the
-# level-2 draws and the counter words stay per request, so the lanes are
-# unchanged.  Request widths are padded to static buckets by the serving
-# layer, which bounds recompiles to one program per (tenant signature, op,
-# bucket) group.
+# one-tenant arena the exact blocked Pallas read of ``batched_fused_sample``,
+# ``batched_prob_of`` and ``batched_kde_query`` packs every request's
+# frontier rows (or query points) into the query tiles of ONE level-1 pass
+# (``_packs``), where the vmap would give each request a pass of its own
+# over the whole dataset -- or, for queries, a materialised (w, n) kernel
+# tile of its own; the noise, the level-2 draws and the counter words stay
+# per request, so the lanes are unchanged.  Request widths are padded to
+# static buckets by the serving layer, which bounds recompiles to one
+# program per (tenant signature, op, bucket) group.
 # --------------------------------------------------------------------- #
 def tenant_slice(a, ti):
     """One request's slice of a stacked arena leaf.  Runs under vmap, so
@@ -1077,17 +1080,17 @@ def _tenant(xa, xa_sq, hstate, ti):
 
 
 def _pallas_sweep(exact, use_pallas, level1) -> bool:
-    """True when a draw or ``prob_of`` reads level 1 with the exact
-    blocked Pallas sweep over the whole dataset."""
+    """True when a draw, ``prob_of`` or query reads level 1 with the
+    exact blocked Pallas sweep over the whole dataset."""
     return exact and use_pallas and level1 == "blocked"
 
 
 def _packs(tenants, exact, use_pallas, level1) -> bool:
-    """True when a batched draw or ``prob_of`` program reads level 1 in
-    ONE Pallas pass over all of its requests' rows: the arena holds one
-    tenant (a static shape, as in ``tenant_slice``) and the read is the
-    Pallas sweep, whose row sums do not depend on the other rows of their
-    query tile.  Otherwise each request reads on its own."""
+    """True when a batched draw, ``prob_of`` or query program reads
+    level 1 in ONE Pallas pass over all of its requests' rows: the arena
+    holds one tenant (a static shape, as in ``tenant_slice``) and the read
+    is the Pallas sweep, whose row sums do not depend on the other rows of
+    their query tile.  Otherwise each request reads on its own."""
     return tenants == 1 and _pallas_sweep(exact, use_pallas, level1)
 
 
@@ -1095,7 +1098,8 @@ def level1_passes(tenants, requests, width, *, exact, use_pallas, level1,
                   bm, **_):
     """Pallas level-1 passes over the dataset that one
     ``batched_fused_sample`` / ``batched_prob_of`` program makes for
-    ``requests`` frontiers of padded ``width`` on an arena of ``tenants``:
+    ``requests`` frontiers of padded ``width`` on an arena of ``tenants``
+    (a ``batched_kde_query`` program makes them only where it packs):
     one per ``bm``-row query tile, ``ceil(R w / bm)`` when the rows pack
     (``_packs``) and ``R ceil(w / bm)`` when each request has its own
     tiles; 0 where the read is no Pallas sweep (jnp, stratified, hashed).
@@ -1160,6 +1164,41 @@ def _packed_prob_of(x, x_sq, src, dst, *, kind, inv_bw, beta, pairwise,
                              l1_reads=w)
 
     return jax.vmap(level2)(src, dst, bs)
+
+
+@_m.scope("level1")
+def _query_rows(x, q, *, kind, inv_bw, beta, block_size, interpret, bm,
+                precision):
+    """One Pallas blocksum pass over the external query points ``q (m,
+    d)``: their plain (m, B) block sums, neither own-block corrected nor
+    floored, with the dataset padded to a block_size multiple at the far
+    offset (kernel values 0)."""
+    m = q.shape[0]
+    return _rk.blocksum_pallas(
+        _pad_rows(q, bm, 0.0), _pad_rows(x, block_size, _PAD_OFFSET), kind,
+        inv_bw, beta, bm=bm, bn=block_size, interpret=interpret,
+        precision=precision)[:m]
+
+
+def _packed_kde_query(x, y, *, kind, inv_bw, beta, block_size, num_blocks,
+                      n, interpret, bm, precision):
+    """``batched_kde_query`` on a one-tenant arena's exact Pallas path:
+    the R w points of ``y (R, w, d)`` share one level-1 pass in request
+    order, then each request's row sums, status and counter word -- the
+    fields ``exact_block_sums`` and the per-lane query fold give."""
+    R, w, d = y.shape
+    bs = _query_rows(x, y.reshape(R * w, d), kind=kind, inv_bw=inv_bw,
+                     beta=beta, block_size=block_size, interpret=interpret,
+                     bm=bm, precision=precision).reshape(R, w, num_blocks)
+
+    def lane(bs_r):
+        est = bs_r.sum(-1)
+        # sums_status carries the NONFINITE bit of the block sums too
+        st = _g.merge(_g.sums_status(bs_r, _ref.BLOCK_SUM_FLOOR),
+                      _g.result_status(est))
+        return est, _c.word(status=st, evals=w * n, l1_reads=w)
+
+    return jax.vmap(lane)(bs)
 
 
 @_jit
@@ -1264,14 +1303,21 @@ def batched_prob_of(xa, xa_sq, tidx, src, dst, keys, hstate=None, *, kind,
 @_jit
 def batched_kde_query(xa, xa_sq, tidx, y, keys, *, kind, inv_bw, beta,
                       pairwise, block_size, num_blocks, n, s, exact,
-                      precision="f32"):
+                      use_pallas=False, interpret=False, bm=128,
+                      level1="blocked", precision="f32"):
     """Definition 1.1 row-sum estimates for R query requests (``y``
     (R, q, d) external points) in ONE program -- the dense level-1 read
     per lane (exact or stratified, matching ``ExactBlockKDE`` /
-    ``StratifiedKDE.query``).  Hash tenants are served by
-    ``kde_hash.ops.batched_hashed_query`` instead.  Returns (estimates
-    (R, q), counter words (R, obs.WIDTH))."""
+    ``StratifiedKDE.query``); on a one-tenant exact Pallas arena one
+    blocksum pass reads every lane's points (``_packs``).  Hash tenants
+    are served by ``kde_hash.ops.batched_hashed_query`` instead.  Returns
+    (estimates (R, q), counter words (R, obs.WIDTH))."""
     TRACE_COUNTS["batched_kde_query"] += 1
+    if _packs(xa.shape[0], exact, use_pallas, level1):
+        return _packed_kde_query(
+            xa[0], y, kind=kind, inv_bw=inv_bw, beta=beta,
+            block_size=block_size, num_blocks=num_blocks, n=n,
+            interpret=interpret, bm=bm, precision=precision)
 
     def one(ti, y_r, key_r):
         x, x_sq, _ = _tenant(xa, xa_sq, None, ti)

@@ -273,6 +273,91 @@ def test_level1_pass_dispatch_rule_and_counter(monkeypatch):
         np.testing.assert_array_equal(r.result[1], np.asarray(p0))
 
 
+_QUERY_KEYS = ("kind", "inv_bw", "beta", "pairwise", "block_size",
+               "num_blocks", "n", "s", "exact", "use_pallas", "interpret",
+               "bm", "level1", "precision")
+
+
+@pytest.mark.parametrize("R,w,bm", [(3, 5, 8), (4, 8, 8), (2, 3, 16)])
+def test_packed_query_pass_matches_per_request_reads(R, w, bm):
+    """On a one-tenant exact Pallas arena ``batched_kde_query`` reads the
+    R w query points in ONE blocksum pass (grid ``ceil(R w / bm)`` row
+    tiles; a request may straddle two): each lane's block sums are bitwise
+    a per-request ``kde_blocksum``, and its estimates, status words
+    (``ZERO_MASS`` for a point far from every row included) and counter
+    words match the jnp sweep's."""
+    from repro.kernels.kde_rowsum.ops import kde_blocksum
+    x = jnp.asarray(_data("packed-query"))
+    x_sq = jnp.sum(x * x, -1)
+    xa, xa_sq = x[None], x_sq[None]
+    cfg = _exact_pallas_cfg(bm)
+    q = {k: cfg[k] for k in _QUERY_KEYS}
+    rng = np.random.default_rng(stats.derive_seed("serving", "pq", R, w))
+    y = rng.normal(0, 0.6, size=(R, w, D)).astype(np.float32)
+    y[-1, -1] = 50.0
+    keys = np.zeros((R, 2), np.uint32)
+    tidx = np.zeros(R, np.int32)
+    nb = cfg["num_blocks"]
+    assert _pallas_grids(_ops.batched_kde_query, xa, xa_sq, tidx, y, keys,
+                         **q) == [(-(-R * w // bm), nb)]
+    est, cw = _ops.batched_kde_query(xa, xa_sq, tidx, y, keys, **q)
+    est0, cw0 = _ops.batched_kde_query(xa, xa_sq, tidx, y, keys,
+                                       **dict(q, use_pallas=False))
+    bs = _ops._query_rows(
+        x, jnp.asarray(y.reshape(R * w, D)), kind="gaussian", inv_bw=1.0,
+        beta=1.0, block_size=16, interpret=True, bm=bm,
+        precision="f32").reshape(R, w, nb)
+    for r in range(R):
+        want = kde_blocksum(y[r], x, gaussian(1.0), bm=bm, bn=16,
+                            interpret=True)
+        np.testing.assert_array_equal(np.asarray(bs[r]), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(est), np.asarray(est0), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(cw), np.asarray(cw0))
+    status = np.asarray(_c.status_of(cw))
+    assert status[-1] & _g.ZERO_MASS and not status[:-1].any()
+    assert np.asarray(cw)[:, 1].tolist() == [w * N] * R
+
+
+def test_query_pass_dispatch_rule_and_counter(monkeypatch):
+    """Both sides of the dispatch rule for queries through the servable,
+    with Pallas on (interpreted): a one-tenant query group packs into
+    ``ceil(R wb / bm)`` passes, a two-tenant group keeps the per-request
+    jnp sweep and counts 0; both answer the exact row sums."""
+    from repro.kernels import platform
+    from repro.obs import metrics as M
+
+    monkeypatch.setattr(platform, "resolve", lambda *a, **k: (True, True))
+    srv = KernelGraphServable(max_resident=4)
+    for name, shift, seed in (("a", 0.0, 3), ("b", 0.8, 4)):
+        srv.add_tenant(name, _data(name, shift), gaussian(1.0),
+                       block_size=16, exact_blocks=True, seed=seed)
+    assert _cfg(srv, "a")["use_pallas"]
+    rng = np.random.default_rng(stats.derive_seed("serving", "pq-rule"))
+    ys = rng.normal(0, 0.6, size=(6, 8, D)).astype(np.float32)
+    M.reset()
+    M.enable()
+    try:
+        one = [srv.submit("a", "query", y=ys[i], seed=600 + i)
+               for i in range(3)]
+        st1 = srv.tick()
+        two = [srv.submit(nm, "query", y=ys[3 + i], seed=610 + i)
+               for i, nm in enumerate("aab")]
+        st2 = srv.tick()
+        counted = M.get_registry()["counters"]["serve.level1_passes"]
+    finally:
+        M.disable()
+        M.reset()
+    assert st1["failed"] == st2["failed"] == 0
+    assert st1["groups"] == st2["groups"] == 1
+    assert st1["level1_passes"] == -(-3 * 8 // 128) == 1
+    assert st2["level1_passes"] == 0 and counted == 1
+    for r, nm in zip(one + two, "aaa" + "aab"):
+        xt = np.asarray(srv.tenant(nm).admit().x, np.float64)
+        d2 = ((r.payload["y"][:, None, :] - xt[None]) ** 2).sum(-1)
+        np.testing.assert_allclose(r.result, np.exp(-d2).sum(-1),
+                                   rtol=1e-5)
+
+
 # ------------------------------------------------------------------- #
 # distributional parity at non-bucket widths (padded lanes)
 # ------------------------------------------------------------------- #

@@ -107,3 +107,23 @@ def test_packed_served_programs_compile_for_v5e(one_chip, op):
     compiled = getattr(ops, op).lower(*args, **cfg).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
+
+
+def test_packed_served_query_compiles_for_v5e(one_chip):
+    """The served query program on a one-tenant arena at the SIFT1M
+    widths (10^6 x 128 in 1000-row blocks, 16 requests of 8 points): the
+    points of every request share ONE Pallas blocksum call, and no (R w,
+    n) kernel tile is materialised (temporaries under the tenant's own
+    bytes)."""
+    from repro.kernels.kde_sampler import ops
+    n, d, bs, r, w = 1_000_000, 128, 1000, 16, 8
+    cfg = dict(kind="gaussian", inv_bw=INV_BW, beta=1.0, pairwise=None,
+               block_size=bs, num_blocks=n // bs, n=n, s=16, exact=True,
+               use_pallas=True, interpret=False, bm=128, level1="blocked",
+               precision="f32")
+    args = [_shape(one_chip, (1, n, d)), _shape(one_chip, (1, n)),
+            _shape(one_chip, (r,), jnp.int32), _shape(one_chip, (r, w, d)),
+            _shape(one_chip, (r, 2), jnp.uint32)]
+    compiled = ops.batched_kde_query.lower(*args, **cfg).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
